@@ -185,7 +185,6 @@ def _timed_pair_us(fn_a, fn_b, iters: int) -> tuple[float, float]:
 
 def run(quick: bool = False, return_payload: bool = False,
         strict: bool = False, breakdown: bool = False):
-    import repro  # noqa: F401  (jax compat shims)
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P

@@ -115,7 +115,6 @@ def _leaf_set(quick: bool):
 
 
 def run(quick: bool = False, return_payload: bool = False):
-    import repro  # noqa: F401  (jax compat shims)
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P

@@ -6,11 +6,12 @@ Prints ``name,us_per_call,derived`` CSV.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced grids/steps (CI mode)")
@@ -33,6 +34,7 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches.items():
         if only and name not in only:
             continue
@@ -42,11 +44,15 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             print(f"{name},0.0,BENCH_ERROR")
+            failed.append(name)
             continue
         for rname, us, derived in rows:
             print(f"{rname},{us:.1f},{derived}", flush=True)
         print(f"{name}:total,{(time.time() - t0) * 1e6:.0f},wall", flush=True)
+    if failed:
+        print(f"benchmarks failed: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

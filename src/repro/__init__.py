@@ -1,4 +1,3 @@
 """Reproduction of "Gradient Sparsification for Communication-Efficient
 Distributed Optimization" (Wangni et al., NIPS 2018) grown toward a
 production-scale jax/pallas training system."""
-from repro import compat as _compat  # noqa: F401  (jax API shims, side effects)

@@ -159,16 +159,6 @@ def bitmap_pack(vals: jax.Array, idx: jax.Array, d: int,
     return svals, jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
-def _pack_bits(bits: jax.Array) -> jax.Array:
-    """Bool bit array (length a multiple of 32, LSB-first per word) ->
-    int32 words via one reshape + weighted sum; the shared word packer of
-    the BITMAP occupancy map's sibling codecs."""
-    w = bits.reshape(-1, WORD_BITS).astype(jnp.uint32)
-    words = jnp.sum(w << jnp.arange(WORD_BITS, dtype=jnp.uint32), axis=-1,
-                    dtype=jnp.uint32)
-    return jax.lax.bitcast_convert_type(words, jnp.int32)
-
-
 def _unpack_bits(words: jax.Array) -> jax.Array:
     """int32 words [..., W] -> int32 bit array [..., W*32], LSB-first."""
     u = jax.lax.bitcast_convert_type(words, jnp.uint32)
@@ -267,26 +257,49 @@ def _rice_pack_gaps(x: jax.Array, r: int,
     """Pack k gap-1 codes at parameter ``r`` into ``cap_words`` int32 words
     (the shared body of ``rice_encode`` and the fitted candidate sweep —
     ``cap_words`` may exceed the minimal capacity, which only widens the
-    zero-padded unary region). Returns ``(words [cap_words], used)``."""
+    zero-padded unary region). Returns ``(words [cap_words], used)``.
+
+    Built word by word, never bit by bit: an array of one element per bit
+    with a 32-wide minor axis takes four to a hundred times its size in
+    TPU memory (tiles pad the minor axis to 128 lanes)."""
     k = x.shape[0]
     q = x >> r
-    u_cap = cap_words * WORD_BITS - k * r
-    # remainder field: k_cap * r bits at offset 0, LSB-first per code
+    # remainder field: code i's r bits at bit offset i*r, LSB-first; a
+    # field spans at most two words, and fields never share a bit, so the
+    # scatter-add is a bitwise or
+    pos = jnp.arange(k, dtype=jnp.int32) * r
+    word, off = pos >> 5, (pos & 31).astype(jnp.uint32)
+    rem = (x & ((1 << r) - 1)).astype(jnp.uint32)
+    words = jnp.zeros((cap_words,), jnp.uint32)
     if r > 0:
-        rp = jnp.arange(k * r, dtype=jnp.int32)
-        rbits = (x[rp // r] >> (rp % r)) & 1
-    else:
-        rbits = jnp.zeros((0,), jnp.int32)
-    # unary field: q_i one-bits then a 0 terminator; terminator i lands at
-    # (inclusive cumsum q)_i + i, always within u_cap by the capacity bound
-    tpos = jnp.cumsum(q) + jnp.arange(k, dtype=jnp.int32)
+        spill = jnp.where(off + r > WORD_BITS,
+                          rem >> ((WORD_BITS - off) & 31), 0)
+        words = words.at[word].add(rem << off).at[word + 1].add(
+            spill.astype(jnp.uint32), mode="drop")
+    # unary field from bit k*r: q_i one-bits then a 0 terminator, i.e. ones
+    # over [k*r, k*r + total_unary) but for the terminators, which land at
+    # k*r + (inclusive cumsum q)_i + i
+    start = jnp.int32(k * r)
     total_unary = jnp.sum(q) + k
-    tmark = jnp.zeros((u_cap,), jnp.int32).at[tpos].set(1, mode="drop")
-    upos = jnp.arange(u_cap, dtype=jnp.int32)
-    ubits = ((upos < total_unary) & (tmark == 0)).astype(jnp.int32)
-    words = _pack_bits(jnp.concatenate([rbits, ubits]))
+    lo = jnp.clip(start - jnp.arange(cap_words, dtype=jnp.int32) * 32, 0, 32)
+    hi = jnp.clip(start + total_unary
+                  - jnp.arange(cap_words, dtype=jnp.int32) * 32, 0, 32)
+    ones = _low_ones(hi) & ~_low_ones(lo)
+    tpos = start + jnp.cumsum(q) + jnp.arange(k, dtype=jnp.int32)
+    terms = jnp.zeros((cap_words,), jnp.uint32).at[tpos >> 5].add(
+        jnp.uint32(1) << (tpos & 31).astype(jnp.uint32), mode="drop")
+    words = words | (ones & ~terms)
     used = (jnp.int32(k * r) + total_unary + (WORD_BITS - 1)) // WORD_BITS
-    return words, used.astype(jnp.int32)
+    return (jax.lax.bitcast_convert_type(words, jnp.int32),
+            used.astype(jnp.int32))
+
+
+def _low_ones(n: jax.Array) -> jax.Array:
+    """uint32 words with their ``n`` low bits set, n in [0, 32]."""
+    full = jnp.uint32(0xFFFFFFFF)
+    return jnp.where(n >= WORD_BITS, full,
+                     (jnp.uint32(1) << (n & 31).astype(jnp.uint32))
+                     - jnp.uint32(1))
 
 
 def rice_fit_cap_words(k_cap: int, d: int, window: tuple[int, ...]) -> int:
@@ -350,33 +363,89 @@ def rice_decode(words: jax.Array, k_cap: int, d: int, r: int) -> jax.Array:
     the tail's zero-quotient codes cumsum to; the receiver must mask them
     by their zero value (repro.comm.wire_layout.unpack_gathered does).
     Batch dims are supported; everything is fixed-shape.
+
+    Word-level throughout, with every gather one-dimensional: the batch
+    dims are folded into a flat word index. A per-bit array, or a gather
+    batched over [workers, layers], would carry a minor axis of 32 bits or
+    of 2-3 index components that TPU tiling pads to 128 lanes.
     """
     batch = words.shape[:-1]
-    bits = _unpack_bits(words)
+    n_words = words.shape[-1]
+    rows = 1
+    for b in batch:
+        rows *= b
+    u = jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(rows, n_words)
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    flat = u.reshape(-1)
     if r > 0:
-        rem = jnp.sum(bits[..., :k_cap * r].reshape(batch + (k_cap, r))
-                      << jnp.arange(r), axis=-1)
+        pos = jnp.arange(k_cap, dtype=jnp.int32) * r
+        word, off = pos >> 5, (pos & 31).astype(jnp.uint32)
+        lo = jnp.take(flat, row * n_words + word) >> off
+        nxt = jnp.take(flat, row * n_words + jnp.minimum(word + 1,
+                                                         n_words - 1))
+        hi = jnp.where(off + r > WORD_BITS, nxt << ((WORD_BITS - off) & 31),
+                       jnp.uint32(0))
+        rem = ((lo | hi) & jnp.uint32((1 << r) - 1)).astype(jnp.int32)
     else:
-        rem = jnp.zeros(batch + (k_cap,), jnp.int32)
-    ub = bits[..., k_cap * r:]
-    u_cap = ub.shape[-1]
+        rem = jnp.zeros((rows, k_cap), jnp.int32)
+    # the unary field, realigned to start at a word boundary; bits past the
+    # end of the message read as ones, so they never count as terminators
+    w0, s = divmod(k_cap * r, WORD_BITS)
+    u_cap = n_words * WORD_BITS - k_cap * r
+    uw = u[:, w0:]
+    if s:
+        nxt = jnp.concatenate(
+            [u[:, w0 + 1:], jnp.full((rows, 1), 0xFFFFFFFF, jnp.uint32)],
+            axis=1)
+        uw = (uw >> s) | (nxt << (WORD_BITS - s))
+    n_u = uw.shape[1]
     # every 0-bit in the unary region terminates a code; the i-th code's
-    # terminator position is the i-th zero, i.e. the first position where
-    # the inclusive zero-count cumsum reaches i + 1 — a vectorized binary
-    # search per code instead of a (serial-on-CPU) u_cap-wide scatter.
-    # Zero padding past the encoded region only appends zeros, so every
-    # rank < k_cap exists (the capacity bound guarantees >= k_cap zeros).
-    cs = jnp.cumsum((ub == 0).astype(jnp.int32), axis=-1)
+    # terminator is the (i+1)-th zero: find its word by a binary search on
+    # the per-word zero counts, then its bit inside that word
+    zeros = WORD_BITS - jax.lax.population_count(uw).astype(jnp.int32)
+    cz = jnp.cumsum(zeros, axis=1)
     tgt = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
-    zpos = jax.vmap(
-        lambda c: jnp.searchsorted(c, tgt, side="left"))(
-            cs.reshape((-1, u_cap))).reshape(batch + (k_cap,)).astype(
-                jnp.int32)
+    wi = _first_at_least(cz.reshape(-1), row * n_u, n_u, tgt)
+    at = row * n_u + jnp.minimum(wi, n_u - 1)
+    nth = tgt - (jnp.take(cz.reshape(-1), at) - jnp.take(zeros.reshape(-1),
+                                                          at))
+    bit = _nth_set_bit(~jnp.take(uw.reshape(-1), at), nth)
+    zpos = jnp.where(wi < n_u, wi * WORD_BITS + bit, u_cap)
     prev = jnp.concatenate(
-        [jnp.full(batch + (1,), -1, jnp.int32), zpos[..., :-1]], axis=-1)
+        [jnp.full((rows, 1), -1, jnp.int32), zpos[:, :-1]], axis=1)
     q = zpos - prev - 1
     gaps = ((q << r) | rem) + 1
-    return jnp.cumsum(gaps, axis=-1) - 1
+    return (jnp.cumsum(gaps, axis=1) - 1).reshape(batch + (k_cap,))
+
+
+def _first_at_least(flat: jax.Array, base: jax.Array, n: int,
+                    tgt: jax.Array) -> jax.Array:
+    """Per row ``b`` (segment ``flat[base_b : base_b + n]``, ascending) and
+    target ``t``: the first index j with ``segment[j] >= t``, or ``n``.
+    A fixed-trip binary search of one-dimensional gathers."""
+    lo = jnp.zeros(jnp.broadcast_shapes(base.shape, tgt.shape), jnp.int32)
+    hi = jnp.full(lo.shape, n, jnp.int32)
+    for _ in range(max(n, 1).bit_length()):
+        mid = (lo + hi) >> 1
+        v = jnp.take(flat, base + jnp.minimum(mid, n - 1))
+        right = (lo < hi) & (v < tgt)
+        lo, hi = (jnp.where(right, mid + 1, lo),
+                  jnp.where((lo < hi) & ~right, mid, hi))
+    return lo
+
+
+def _nth_set_bit(x: jax.Array, n: jax.Array) -> jax.Array:
+    """Bit position of the ``n``-th (1-based) set bit of uint32 ``x``, by
+    halving: keep to the low half while it holds at least n set bits."""
+    pos = jnp.zeros(x.shape, jnp.int32)
+    for width in (16, 8, 4, 2, 1):
+        low = jax.lax.population_count(
+            x & jnp.uint32((1 << width) - 1)).astype(jnp.int32)
+        up = n > low
+        n = jnp.where(up, n - low, n)
+        x = jnp.where(up, x >> width, x)
+        pos = pos + jnp.where(up, width, 0)
+    return pos
 
 
 def bitmap_select(words: jax.Array, vals: jax.Array, d: int) -> jax.Array:

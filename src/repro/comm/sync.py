@@ -185,7 +185,7 @@ def _compact_items(cfg: CompressionConfig, leaves: list, stk_leaves: list):
 
     scheme = cfg.scheme()
     codec = scheme.codec
-    batched = resolve_backend(cfg.backend, cfg.kernel_interpret).batched_emit
+    batched = resolve_backend(cfg.backend).batched_emit
     plan = plan_tree(cfg, leaves, stk_leaves)
     items = []
     for grp in plan.groups:
@@ -244,24 +244,6 @@ def _compaction_drops(items: list, leaves: list) -> list:
             drops[i] = drop.reshape(leaf.shape).astype(leaf.dtype)
             r0 += rows
     return drops
-
-
-def _strip_prepack(items: list) -> list:
-    """Drop kernel-prepacked STATIC-format RICE streams from sparse items.
-    The pallas output pass bit-packs the rice words at the static parameter
-    ``coding.rice_parameter``; under ``cfg.rice_fitted`` the wire carries
-    the FITTED format (different capacity, header-tagged counts), so the
-    prepack must be discarded and the streams re-encoded by
-    ``wire_layout.pack`` — the compact (values, idx) pair is authoritative
-    either way."""
-    out = []
-    for kind, payload, members in items:
-        if kind == "sparse" and getattr(payload, "rice_words", None) \
-                is not None:
-            payload = dataclasses.replace(payload, rice_words=None,
-                                          rice_used=None)
-        out.append((kind, payload, members))
-    return out
 
 
 def _apply_skip(cfg: CompressionConfig, items: list, skip_flags: list):
@@ -986,8 +968,6 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
                                                         send_grads,
                                                         stacked=stacked,
                                                         residual=residual)
-        if cfg.rice_fitted:
-            items = _strip_prepack(items)
         skip_savings = None
         if cfg.adaptive:
             items, skip_savings = _apply_skip(cfg, items, skip_flags)
@@ -1037,8 +1017,6 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
                                                            stacked=stacked)
             else:
                 items2 = _compact_items(cfg, synced_leaves, stk_leaves)
-            if cfg.rice_fitted:
-                items2 = _strip_prepack(items2)
             if not cfg.resparsify_pods:
                 if cfg.error_feedback:
                     # the pod-union of the data-axis workers' coordinates

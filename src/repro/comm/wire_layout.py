@@ -150,10 +150,9 @@ def pack(sg, lp: LeafPlan) -> tuple[jax.Array, jax.Array, jax.Array]:
     fixed layouts, whose idx_len IS the realized length); they feed the
     two-phase exchange's phase-one vector and the true-byte accounting.
     Coordinate-sorted producers (``sg.idx_sorted``) pack bitmap and rice
-    sort-free from their authoritative nnz. A leaf whose kernel already
-    bit-packed the RICE stream in its output pass (``sg.rice_words``) ships
-    those words as-is — they are bit-identical to ``rice_encode`` on the
-    compact pair, and the values buffer is already in coordinate order."""
+    sort-free from their authoritative nnz. A leaf whose RICE stream is
+    already packed (``sg.rice_words``, set by the adaptive loop's skip
+    masking) ships those words as-is."""
     if lp.layout == "rice" and sg.rice_words is not None:
         if sg.values.ndim == 2:
             return sg.values, sg.rice_words, sg.rice_used

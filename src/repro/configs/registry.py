@@ -49,6 +49,10 @@ class ArchSpec:
     rules_overrides: dict[str, Any] = dataclasses.field(default_factory=dict)
     train_mode: str = "compressed"    # compressed (Alg.1) | fsdp (+step-7 Q)
     notes: str = ""
+    chip: ModelConfig | None = None   # one chip's share at published widths
+    reduced: dict[str, str] = dataclasses.field(default_factory=dict)
+                                      # chip config: each cut -> what it
+                                      # stands for in the deployment
 
     def batch_inputs(self, shape_name: str) -> dict:
         """Extra (non-token) model inputs per shape, as (shape, dtype) specs.

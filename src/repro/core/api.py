@@ -89,7 +89,6 @@ class CompressionConfig:
     min_leaf_size: int = 256         # leaves smaller than this are sent dense
     # backend selection (consumed by repro.core.sparse)
     backend: str = "auto"            # auto | reference | pallas
-    kernel_interpret: bool | None = None  # force pallas interpret mode (None=auto)
     # wire/sync settings (consumed by repro.comm)
     wire: str = "dense"              # dense | gather | packed
     wire_layout: str = "auto"        # auto | coo | bitmap | dense | rice —
@@ -448,7 +447,7 @@ def compress_tree_sparse(cfg: CompressionConfig, key: jax.Array, grads: Any,
     from repro.core.sparse import resolve_backend
 
     _require_residual(cfg, residual, "compress_tree_sparse")
-    backend = resolve_backend(cfg.backend, cfg.kernel_interpret)
+    backend = resolve_backend(cfg.backend)
     ef = cfg.error_feedback
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     res_leaves = (jax.tree_util.tree_flatten(residual)[0]

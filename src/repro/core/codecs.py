@@ -164,8 +164,13 @@ class QsgdCodec:
         return (jnp.sign(v) * level).astype(self.wire_dtype(vals.dtype))
 
     def decode(self, wire_vals: jax.Array, scale: jax.Array) -> jax.Array:
+        # multiply by the reciprocal, never divide by the level count: XLA
+        # rewrites a division by a constant into exactly this product under
+        # jit but not in eager dispatch, so a division would decode to
+        # different last bits in and out of jit
         return (wire_vals.astype(jnp.float32)
-                * (jnp.asarray(scale, jnp.float32) / self.levels))
+                * (jnp.asarray(scale, jnp.float32)
+                   * jnp.float32(1.0 / self.levels)))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -22,15 +22,14 @@ Backends (``CompressionConfig.backend``):
                computation — which the dense-vs-gather equivalence tests
                rely on for every composition.
   pallas    -- the two-pass emit pipeline from repro.kernels.sparsify:
-               pass 1 reduces per-tile survivor counts and the codec scale
-               statistic in one traversal, pass 2 writes the codec-encoded
-               compact (values, idx) buffers directly from the tiles, with
-               Golomb-Rice index packing fused into the same output pass
-               under the RICE layout. Covers the gspar (greedy + closed),
-               unisp, topk and bernoulli selectors; identity falls back to
-               reference per leaf. The wire buffer is the kernel's only
-               large output — everything downstream is O(k_cap). Off-TPU
-               the kernels run in interpreter mode.
+               pass 1 reduces per-tile survivor counts in one traversal,
+               pass 2 ranks every survivor from the tile offsets, and one
+               XLA scatter builds the compact (values, idx) buffers, which
+               the codec encodes as the reference does. Sort-free: the
+               valid prefix ascends by coordinate. Covers the gspar
+               (greedy + closed), unisp, topk and bernoulli selectors;
+               identity falls back to reference per leaf. On a TPU the
+               kernels are compiled; off-TPU they run in interpreter mode.
   auto      -- pallas on TPU, reference elsewhere.
 """
 from __future__ import annotations
@@ -82,12 +81,9 @@ class SparseGrad:
                              # pallas counting compaction); lets the bitmap
                              # layout pack without an argsort
     rice_words: jax.Array | None = None
-                             # pre-packed Golomb-Rice index words emitted by
-                             # the fused kernel's output pass (RICE layout on
-                             # the pallas backend only; None elsewhere).
-                             # Bit-identical to compaction.rice_encode on
-                             # (values, idx) — wire_layout.pack ships them
-                             # as-is instead of re-encoding.
+                             # pre-packed Golomb-Rice index words (set by
+                             # the adaptive loop's skip masking, None
+                             # elsewhere); wire_layout.pack ships them as-is
     rice_used: jax.Array | None = None
                              # used word count of the pre-packed stream
 
@@ -266,13 +262,11 @@ class ReferenceBackend:
 
 
 class PallasBackend:
-    """Two-pass fused kernel path (repro.kernels.sparsify): pass 1 reduces
-    per-tile survivor counts and the codec's scale statistic, pass 2 writes
-    the codec-encoded compact ``(values, idx)`` wire buffers straight from
-    the tiles — and, under the RICE layout, bit-packs the Golomb-Rice index
-    stream in the same output pass. The kernel's only large outputs are the
-    wire buffers (plus the in-pass EF residual); everything after it is
-    O(k_cap) accounting, never a second O(d) traversal.
+    """Two-pass kernel path (repro.kernels.sparsify): pass 1 reduces
+    per-tile survivor counts and the accounting sums, pass 2 ranks the
+    survivors (plus the in-pass EF residual for float codecs), and one XLA
+    scatter builds the compact ``(values, idx)`` wire buffers, which the
+    codec encodes as on the reference backend.
 
     Fused selectors: gspar (greedy *and* closed-form lambda), unisp, topk,
     and bernoulli (TernGrad's selection). The identity selector has no
@@ -313,8 +307,8 @@ class PallasBackend:
             sg = self._finish(scheme, g, er, layout, s)
             return sg, _residual_from_buffers(g, sg)
         # float codecs: the kernel emits the residual g - Q(g) in the same
-        # output pass (one extra HBM write, no extra read); the encoded
-        # value is what gets subtracted, so bf16 rounding of kept values is
+        # pass (one extra HBM write, no extra read); it subtracts the value
+        # rounded to the wire dtype, so bf16 rounding of kept values is
         # already charged to the residual.
         er, layout, s = self._emit(cfg, scheme, key, g, k_cap, ef=True)
         sg = self._finish(scheme, g, er, layout, s)
@@ -329,17 +323,12 @@ class PallasBackend:
         sel, codec = scheme.selector, scheme.codec
         flat = g.reshape(-1)
         d = flat.shape[0]
-        # the layout is a static property of (k_cap, d, wire width), so it
-        # is decided *before* the kernel: under the RICE layout the kernel
-        # packs the index words itself and wire_layout.pack ships them.
         layout = _choose_layout(cfg, codec, g.dtype, k_cap, d)
-        rice_r = coding.rice_parameter(k_cap, d) if layout == "rice" else -1
         k_sel, k_cod = scheme.split_key(key)
-        # codec uniforms at compact rank (k_cap draws, gathered in-kernel)
+        # codec uniforms at compact rank (k_cap draws)
         u_cod = (jax.random.uniform(k_cod, (k_cap,), jnp.float32)
                  if codec.stochastic else None)
-        kw = dict(k_cap=k_cap, codec=codec, rice_r=rice_r, ef=ef,
-                  interpret=self.interpret)
+        kw = dict(k_cap=k_cap, codec=codec, ef=ef, interpret=self.interpret)
         if sel.name == "topk":
             er = ops.topk_emit(flat, u_cod, k_target=sel.k_target(d), **kw)
             return er, layout, None
@@ -405,18 +394,18 @@ class PallasBackend:
                           p_sum=p_sum, bits=bits, var_ratio=var,
                           scale=er.scale, d=d, shape=tuple(g.shape),
                           codec=codec.name, layout=layout,
-                          idx_sorted=True,  # tile-sequential compaction:
-                                            # the valid prefix ascends by
-                                            # coordinate
-                          rice_words=er.rice_words, rice_used=er.rice_used)
+                          idx_sorted=True)  # rank order is coordinate
+                                            # order: the valid prefix
+                                            # ascends
 
 
-def resolve_backend(name: str, interpret: bool | None = None) -> Backend:
+def resolve_backend(name: str) -> Backend:
     """Backend registry with automatic platform fallback.
 
-    ``auto`` picks pallas on TPU (compiled kernels) and reference elsewhere.
-    An explicit ``pallas`` off-TPU runs the kernels in interpreter mode so
-    the fused path stays testable on CPU.
+    ``auto`` picks pallas on TPU and reference elsewhere. ``pallas`` runs
+    compiled kernels on a TPU and never interprets them there; off-TPU it
+    runs the kernels in interpreter mode so the fused path stays testable
+    on CPU.
     """
     on_tpu = jax.default_backend() == "tpu"
     if name == "auto":
@@ -424,7 +413,6 @@ def resolve_backend(name: str, interpret: bool | None = None) -> Backend:
     if name == "reference":
         return ReferenceBackend()
     if name == "pallas":
-        return PallasBackend(interpret=(not on_tpu) if interpret is None
-                             else interpret)
+        return PallasBackend(interpret=not on_tpu)
     raise ValueError(f"unknown backend {name!r}; "
                      "have ('auto', 'reference', 'pallas')")

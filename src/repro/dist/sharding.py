@@ -17,7 +17,6 @@ import contextlib
 import threading
 from typing import Any
 
-import repro.compat  # noqa: F401  (jax API shims must precede jax use)
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -163,15 +162,11 @@ def activation_sharding(rules: dict, mesh):
 
 
 def _in_manual_region() -> bool:
-    """True while tracing inside a shard_map/pmap body. Older jax's SPMD
-    partitioner aborts on full-mesh sharding constraints emitted from
-    partial-manual regions, so ``logical_constraint`` degrades to identity
-    there (the constraint is only a layout hint)."""
-    probe = getattr(jax.core, "nonempty_axis_env_DO_NOT_USE", None)
-    try:
-        return bool(probe()) if probe is not None else False
-    except Exception:
-        return False
+    """True while tracing inside a shard_map/pmap body. The ambient rules
+    hold a concrete full mesh, which a manual region cannot re-constrain
+    onto, so ``logical_constraint`` is the identity there (the constraint
+    is only a layout hint)."""
+    return bool(jax.core.nonempty_axis_env_DO_NOT_USE())
 
 
 def logical_constraint(x: jax.Array, axes) -> jax.Array:
@@ -186,9 +181,4 @@ def logical_constraint(x: jax.Array, axes) -> jax.Array:
     spec = resolve_spec(x.shape, axes, rules, mesh)
     if all(e is None for e in tuple(spec)):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
-        # Inside manual shard_map sub-regions older jax cannot re-constrain
-        # onto the full mesh; the constraint is a hint, so degrade to identity.
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

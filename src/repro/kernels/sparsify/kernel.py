@@ -14,26 +14,25 @@ Kernel families:
                (sum|g|, max|g|) pair the greedy lambda actually consumes,
                skipping one VMEM reduction on the sparse path.
   two-pass compaction -- ``select_stats_2d`` (pass 1) runs the selector per
-               tile and reduces survivor counts, p-accounting, and the
-               codec-scale statistics in one traversal; ``compact_emit_2d``
-               (pass 2) re-derives the kept mask and writes the compact
-               wire buffers directly — codec-encoded values, ascending
-               coordinates, and (optionally) the Golomb-Rice index stream
-               bit-packed in the same output pass. The kernel's only large
-               output IS the wire buffer: no dense Q materialization, no
-               post-kernel encode, no separate rice_encode pass.
+               tile and reduces per-tile survivor counts, p-accounting and
+               the variance denominator in one traversal;
+               ``compact_emit_2d`` (pass 2) re-derives the kept mask and
+               gives every survivor its global compact rank (the tile's
+               offset plus an in-tile prefix count on the MXU). One XLA
+               scatter of (rank, value) then builds the compact
+               ``(values, idx)`` wire buffers: Mosaic has no lowering for
+               cumsum, vector scatter or 1-D gather, so nothing of size
+               k_cap lives in the kernels.
 
 Block layout: inputs are reshaped to [R, C] with C a multiple of 128 and
 R a multiple of 8; tiles of (BLOCK_R, BLOCK_C) f32 live in VMEM
 (3 x 128 x 512 x 4 B = 768 KB working set, well under the ~16 MB/core VMEM).
 The two-pass kernels additionally REQUIRE C == BLOCK_C (which the ops-layer
 ``_pad_2d`` always produces): the grid then walks row-blocks of contiguous
-flat coordinates, so tile-sequential compaction is counting compaction in
-ascending coordinate order by construction — the ``SparseGrad.idx_sorted``
-contract falls out of the layout instead of needing a sort. Cross-tile
-state (compact rank, previous kept coordinate, unary-bit offset) rides
-(1, 1) SMEM accumulators across the sequential TPU grid, the same
-mechanism the stats kernels use.
+flat coordinates, and row-major order inside a tile is ascending coordinate
+order, so rank order is coordinate order — the ``SparseGrad.idx_sorted``
+contract falls out of the layout instead of needing a sort. Tiles are never
+flattened: every in-kernel array stays 2-D.
 """
 from __future__ import annotations
 
@@ -46,12 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_R = 128
 BLOCK_C = 512
-TILE = BLOCK_R * BLOCK_C
-WORD_BITS = 32
-
-# selector kinds the two-pass kernels implement; "lam" covers both gspar
-# solvers (greedy and closed-form hand the kernel a scalar lambda)
-SELECT_KINDS = ("lam", "rho", "bern", "topk")
 
 
 def _sparsify_body(g_ref, u_ref, lam_ref, out_ref):
@@ -84,7 +77,10 @@ def _sparsify_prng_body(g_ref, lam_ref, seed_ref, out_ref):
     i, j = pl.program_id(0), pl.program_id(1)
     pltpu.prng_seed(seed_ref[0, 0] + i * pl.num_programs(1) + j)
     bits = pltpu.prng_random_bits(g_ref.shape)
-    u = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))   # [0, 1)
+    # the bits come as int32: an arithmetic shift would make half the draws
+    # negative, and a negative draw keeps every coordinate with p > 0
+    u = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) \
+        * (1.0 / (1 << 24))                                    # [0, 1)
     g = g_ref[...].astype(jnp.float32)
     lam = lam_ref[0, 0]
     p = jnp.minimum(lam * jnp.abs(g), 1.0)
@@ -296,16 +292,40 @@ def stats_l1max_2d(g: jax.Array, interpret: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# Two-pass compaction: the wire buffer is the kernel's only large output.
+# Two-pass compaction: pass 1 counts survivors per tile, pass 2 ranks them.
 # ---------------------------------------------------------------------------
 
-def _tile_select(pkind: str, g, a, u, s1, s2, tie_base):
-    """Selector applied to one tile, flattened in lane order (== ascending
-    flat coordinate, since the two-pass layout requires C == BLOCK_C).
+def prefix_operands() -> tuple[jax.Array, jax.Array]:
+    """The 0/1 matrices ``_tile_prefix`` multiplies by, in bf16 (0 and 1 are
+    exact there): ``up[k, j] = k <= j`` over lanes, ``lo[r, k] = k < r`` over
+    rows. Built outside the kernels and held in VMEM by a constant index
+    map, so each call loads them once."""
+    c = jnp.arange(BLOCK_C)
+    r = jnp.arange(BLOCK_R)
+    return ((c[:, None] <= c[None, :]).astype(jnp.bfloat16),
+            (r[None, :] < r[:, None]).astype(jnp.bfloat16))
 
-    Returns flat (p, z, v, ties) with p the keep probability, z the kept
-    mask, v the transmitted full-precision value, and ties the tile's count
-    of at-threshold coordinates (topk only; 0 otherwise). The arithmetic
+
+def _tile_prefix(x, up, lo):
+    """Inclusive prefix count of a boolean tile in row-major order, which is
+    ascending flat coordinate order under the C == BLOCK_C layout. Mosaic
+    has no cumsum lowering, so the prefix is two MXU matmuls with the
+    triangular 0/1 matrices: ``x @ up`` counts each row up to lane j, and
+    the lane sum of ``lo @ x`` counts the rows above. Every partial sum is
+    an integer <= BLOCK_R * BLOCK_C = 2^16, exact in f32."""
+    xb = x.astype(jnp.bfloat16)
+    within = jnp.dot(xb, up, preferred_element_type=jnp.float32)
+    above = jnp.sum(jnp.dot(lo, xb, preferred_element_type=jnp.float32),
+                    axis=1, keepdims=True)
+    return (within + above).astype(jnp.int32)
+
+
+def _tile_select(pkind: str, g, a, u, s1, s2, tie_base, up, lo):
+    """Selector applied to one 2-D tile.
+
+    Returns (p, z, v, ties) with p the keep probability, z the kept mask, v
+    the transmitted full-precision value, and ties the tile's count of
+    at-threshold coordinates (topk only; 0 otherwise). The arithmetic
     replicates the reference selectors bit-for-bit:
 
       lam  -- gspar (greedy or closed-form): p = min(s1 * |g|, 1)
@@ -313,318 +333,153 @@ def _tile_select(pkind: str, g, a, u, s1, s2, tie_base):
       bern -- bernoulli/TernGrad: p = |g| / s2 (s2 = max|g|)
       topk -- deterministic: keep |g| > s1, plus the first s2 coordinates
               with |g| == s1 (XLA top_k breaks ties by lowest index, so the
-              in-coordinate-order tie budget reproduces its selection)
+              in-coordinate-order tie budget reproduces its selection);
+              ``tie_base`` counts the ties in earlier tiles
     """
-    gf = g.reshape(-1)
-    af = a.reshape(-1)
     if pkind == "topk":
         t = s1
-        budget = s2.astype(jnp.int32)
-        tie = ((af == t) & (t > 0)).astype(jnp.int32)
-        tie_rank = tie_base + jnp.cumsum(tie) - tie          # exclusive
-        z = (af > t) | ((tie == 1) & (tie_rank < budget))
+        tie = (a == t) & (t > 0)
+        ti = tie.astype(jnp.int32)
+        tie_rank = tie_base + _tile_prefix(tie, up, lo) - ti     # exclusive
+        z = (a > t) | (tie & (tie_rank < s2.astype(jnp.int32)))
         p = z.astype(jnp.float32)
-        v = jnp.where(z, gf, 0.0)
-        return p, z, v, jnp.sum(tie)
+        v = jnp.where(z, g, 0.0)
+        return p, z, v, jnp.sum(ti)
     if pkind == "lam":
-        p = jnp.minimum(s1 * af, 1.0)
+        p = jnp.minimum(s1 * a, 1.0)
     elif pkind == "rho":
-        p = jnp.where(af > 0, s1, 0.0)
+        p = jnp.where(a > 0, s1, 0.0)
     elif pkind == "bern":
-        p = jnp.where(s2 > 0, af / jnp.where(s2 > 0, s2, 1.0), 0.0)
-    else:  # pragma: no cover - guarded by SELECT_KINDS at the ops layer
+        p = jnp.where(s2 > 0, a / jnp.where(s2 > 0, s2, 1.0), 0.0)
+    else:  # pragma: no cover - the ops layer passes only the four kinds
         raise ValueError(f"unknown select kind {pkind!r}")
-    z = u.reshape(-1) < p
+    z = u < p
     safe_p = jnp.where(p > 0, p, 1.0)
-    v = jnp.where(z, gf / safe_p, 0.0)
+    v = jnp.where(z, g / safe_p, 0.0)
     return p, z, v, jnp.zeros((), jnp.int32)
 
 
-def _coords(i):
-    """Ascending flat coordinates of tile i (C == BLOCK_C layout)."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1).reshape(-1)
-    return i * TILE + lane
-
-
-def _select_stats_body(g_ref, u_ref, s1_ref, s2_ref,
-                       cnt_ref, nzc_ref, psum_ref, den_ref,
-                       vsq_ref, vmx_ref, tie_ref,
-                       *, pkind: str, k_cap: int):
+def _select_stats_body(g_ref, u_ref, s1_ref, s2_ref, up_ref, lo_ref,
+                       tiles_ref, psum_ref, den_ref, tie_ref, *, pkind: str):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        cnt_ref[0, 0] = 0
-        nzc_ref[0, 0] = 0
-        tie_ref[0, 0] = 0
         psum_ref[0, 0] = 0.0
         den_ref[0, 0] = 0.0
-        vsq_ref[0, 0] = 0.0
-        vmx_ref[0, 0] = 0.0
+        tie_ref[0, 0] = 0
 
     g = g_ref[...].astype(jnp.float32)
     a = jnp.abs(g)
-    p, z, v, ties = _tile_select(pkind, g, a, u_ref[...],
-                                 s1_ref[0, 0], s2_ref[0, 0], tie_ref[0, 0])
-    zi = z.astype(jnp.int32)
-    # global compact rank of each survivor; the codec scale only sees the
-    # first k_cap (what the wire actually carries)
-    rank = cnt_ref[0, 0] + jnp.cumsum(zi) - zi
-    keep = z & (rank < k_cap)
-    vk = jnp.where(keep, v, 0.0)
-    vsq_ref[0, 0] += jnp.sum(vk * vk)
-    vmx_ref[0, 0] = jnp.maximum(vmx_ref[0, 0], jnp.max(jnp.abs(vk)))
+    p, z, _, ties = _tile_select(pkind, g, a, u_ref[...], s1_ref[0, 0],
+                                 s2_ref[0, 0], tie_ref[0, 0], up_ref[...],
+                                 lo_ref[...])
+    tiles_ref[0, i] = jnp.sum(z.astype(jnp.int32))
+    tiles_ref[1, i] = ties
     psum_ref[0, 0] += jnp.sum(p)
     den_ref[0, 0] += jnp.sum(a * a)
-    nzc_ref[0, 0] += jnp.sum((a > 0).astype(jnp.int32))
-    cnt_ref[0, 0] += jnp.sum(zi)
     tie_ref[0, 0] += ties
 
 
-def select_stats_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
-                    k_cap: int, pkind: str, interpret: bool = False):
-    """Pass 1 of the two-pass compaction: run the selector per tile and
-    reduce, in one traversal of g, everything the backend needs *before*
-    the compact write — survivor count, support size, sum of keep
-    probabilities, sum g^2 (the variance denominator), and the codec-scale
-    statistics over the first k_cap survivors (sum of squares for qsgd's
-    l2 scale, max|v| for ternary's).
+def _smem(block=(1, 1)):
+    return pl.BlockSpec(block, lambda i: (0, 0), memory_space=pltpu.SMEM)
 
-    Returns (nnz, nonzeros, p_sum, den, sum_sq, max_abs) as 0-d arrays.
-    """
+
+def select_stats_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
+                    up: jax.Array, lo: jax.Array, pkind: str,
+                    interpret: bool = False):
+    """Pass 1 of the two-pass compaction: run the selector per tile and
+    reduce, in one traversal of g, what pass 2 and the accounting need —
+    per-tile survivor and at-threshold tie counts (``tiles[0, t]`` and
+    ``tiles[1, t]``, an SMEM output indexed by the grid), the sum of keep
+    probabilities and sum g^2 (the variance denominator).
+
+    Returns (tiles [2, T] int32, p_sum, den)."""
     r, c = g.shape
     assert c == BLOCK_C, "two-pass kernels require the _pad_2d layout"
     grid = (r // BLOCK_R,)
     s1_2 = jnp.asarray(s1, jnp.float32).reshape(1, 1)
     s2_2 = jnp.asarray(s2, jnp.float32).reshape(1, 1)
-    smem = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
-    out = pl.pallas_call(
-        functools.partial(_select_stats_body, pkind=pkind, k_cap=k_cap),
+    tile = pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0))
+    tiles, psum, den, _tie = pl.pallas_call(
+        functools.partial(_select_stats_body, pkind=pkind),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0)),
-            smem, smem,
-        ],
-        out_specs=[smem] * 7,
-        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        in_specs=[tile, tile, _smem(), _smem(),
+                  pl.BlockSpec((BLOCK_C, BLOCK_C), lambda i: (0, 0)),
+                  pl.BlockSpec((BLOCK_R, BLOCK_R), lambda i: (0, 0))],
+        out_specs=[_smem((2, grid[0])), _smem(), _smem(), _smem()],
+        out_shape=[jax.ShapeDtypeStruct((2, grid[0]), jnp.int32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
         name=f"select_stats_{pkind}",
-    )(g, u, s1_2, s2_2)
-    cnt, nzc, psum, den, vsq, vmx, _tie = out
-    return (cnt[0, 0], nzc[0, 0], psum[0, 0], den[0, 0],
-            vsq[0, 0], vmx[0, 0])
+    )(g, u, s1_2, s2_2, up, lo)
+    return tiles, psum[0, 0], den[0, 0]
 
 
-def _u32(x):
-    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+# slot of a coordinate that is not kept: past any capacity, so the scatter
+# that builds the compact buffers drops it
+DROP_SLOT = 2**31 - 1
 
 
-def _i32(x):
-    return jax.lax.bitcast_convert_type(x, jnp.int32)
-
-
-def _compact_emit_body(refs, *, pkind: str, codec, k_cap: int, rice_r: int,
-                       ef: bool, cap_words: int, u_cap: int, t_last: int):
-    """Pass 2: re-derive the kept mask per tile and scatter the compact wire
-    buffers. Cross-tile state (compact rank, previous kept coordinate,
-    running unary-bit count, topk tie count) rides (1,1) SMEM accumulators;
-    the whole-buffer outputs use a constant index map so every grid step
-    sees the same VMEM block (the standard accumulate pattern)."""
-    it = iter(refs)
-    g_ref, u_ref, s1_ref, s2_ref, scale_ref, ucod_ref = (
-        next(it), next(it), next(it), next(it), next(it), next(it))
-    vals_ref, idx_ref = next(it), next(it)
-    rank_ref, prev_ref, qsum_ref, tie_ref = (
-        next(it), next(it), next(it), next(it))
-    rice = rice_r >= 0
-    if rice:
-        words_ref, used_ref, tmark_ref = next(it), next(it), next(it)
-    if ef:
-        res_ref = next(it)
-
+def _compact_emit_body(g_ref, u_ref, s1_ref, s2_ref, up_ref, lo_ref, off_ref,
+                       slot_ref, val_ref, *res_ref, pkind: str, wire_dtype):
     i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        vals_ref[...] = jnp.zeros_like(vals_ref)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-        rank_ref[0, 0] = 0
-        prev_ref[0, 0] = -1
-        qsum_ref[0, 0] = 0
-        tie_ref[0, 0] = 0
-        if rice:
-            words_ref[...] = jnp.zeros_like(words_ref)
-            tmark_ref[...] = jnp.zeros_like(tmark_ref)
-            used_ref[0, 0] = 0
-
     g = g_ref[...].astype(jnp.float32)
-    a = jnp.abs(g)
-    p, z, v, ties = _tile_select(pkind, g, a, u_ref[...],
-                                 s1_ref[0, 0], s2_ref[0, 0], tie_ref[0, 0])
-    zi = z.astype(jnp.int32)
-    rank = rank_ref[0, 0] + jnp.cumsum(zi) - zi          # global compact rank
-    keep = z & (rank < k_cap)
-    coord = _coords(i)
-
-    # fused value codec: elementwise given the pass-1 scale, with the
-    # codec's pregenerated uniform gathered at the compact rank — exactly
-    # the draw codec.encode sees on the compact buffer downstream
-    scale = scale_ref[0, 0]
-    if codec.stochastic:
-        u_cod = ucod_ref[0, :][jnp.clip(rank, 0, k_cap - 1)]
-        ev = codec.encode(v, scale, u_cod)
-    else:
-        ev = codec.encode(v, scale)
-    slot = jnp.where(keep, rank, k_cap)                  # k_cap -> dropped
-    vals_ref[...] = vals_ref[...][0].at[slot].set(
-        ev.astype(vals_ref.dtype), mode="drop")[None]
-    idx_ref[...] = idx_ref[...][0].at[slot].set(coord, mode="drop")[None]
-
-    if ef:
-        # residual in the same pass: subtract what the wire carries (post
-        # codec rounding), for ALL survivors — overflow-dropped ones were
-        # sampled, just not transmitted (documented fused-EF semantics)
-        res = g - jnp.where(z, ev.astype(jnp.float32), 0.0).reshape(g.shape)
-        res_ref[...] = res.astype(res_ref.dtype)
-
-    if rice:
-        # Golomb-Rice index packing fused into the same output pass. The
-        # stream is [k_cap*r remainder bits | unary field]; live code i
-        # (i == rank) stores the low r bits of x = delta-1 at bit offset
-        # i*r, and its unary terminator at position cumsum(q)_i + i of the
-        # unary field. Dead (padding) codes contribute only zero bits, so
-        # scattering live codes into zero-initialized words is bit-exact
-        # with compaction.rice_encode.
-        mc = jnp.where(keep, coord, -1)
-        inc = jax.lax.cummax(mc)
-        exc = jnp.concatenate([jnp.full((1,), -1, jnp.int32), inc[:-1]])
-        prev = jnp.maximum(exc, prev_ref[0, 0])
-        x = jnp.where(keep, coord - prev - 1, 0)
-        q = x >> rice_r if rice_r > 0 else x
-        words = _u32(words_ref[...][0])
-        if rice_r > 0:
-            rem = (x & ((1 << rice_r) - 1)).astype(jnp.uint32)
-            bitpos = rank * rice_r
-            w_lo = jnp.where(keep, bitpos >> 5, cap_words)
-            sh = (bitpos & 31).astype(jnp.uint32)
-            lo_add = jnp.where(keep, rem << sh, jnp.uint32(0))
-            # straddle into the next word; shift amount kept in [0, 31]
-            sh_hi = jnp.where(sh > 0, jnp.uint32(32) - sh, jnp.uint32(0))
-            straddle = keep & (sh > 0)
-            w_hi = jnp.where(straddle, (bitpos >> 5) + 1, cap_words)
-            hi_add = jnp.where(straddle, rem >> sh_hi, jnp.uint32(0))
-            words = words.at[w_lo].add(lo_add, mode="drop")
-            words = words.at[w_hi].add(hi_add, mode="drop")
-        words_ref[...] = _i32(words)[None]
-        qk = jnp.where(keep, q, 0)
-        tpos = qsum_ref[0, 0] + jnp.cumsum(qk) + rank    # terminator position
-        tslot = jnp.where(keep, tpos, u_cap)
-        tmark_ref[...] = tmark_ref[...][0].at[tslot].set(
-            1, mode="drop")[None]
-        qsum_ref[0, 0] += jnp.sum(qk)
-        prev_ref[0, 0] = jnp.maximum(prev_ref[0, 0], jnp.max(mc))
-
-    rank_ref[0, 0] += jnp.sum(zi)
-    tie_ref[0, 0] += ties
-
-    if rice:
-        @pl.when(i == t_last)
-        def _finalize():
-            # unary field: one-bits everywhere below the last live
-            # terminator except at the terminators themselves. The dead
-            # region [live_end, total_unary) is all terminators, i.e. all
-            # zero bits — identical to rice_encode's full-field scatter.
-            qs = qsum_ref[0, 0]
-            n_live = jnp.minimum(rank_ref[0, 0], k_cap)
-            live_end = qs + n_live
-            upos = jax.lax.broadcasted_iota(jnp.int32, (1, u_cap),
-                                            1).reshape(-1)
-            ub = ((upos < live_end)
-                  & (tmark_ref[...][0] == 0)).astype(jnp.uint32)
-            abs_bit = k_cap * rice_r + upos
-            add = ub << (abs_bit & 31).astype(jnp.uint32)
-            words = _u32(words_ref[...][0]).at[abs_bit >> 5].add(add)
-            words_ref[...] = _i32(words)[None]
-            used_ref[0, 0] = (k_cap * rice_r + qs + k_cap
-                              + WORD_BITS - 1) // WORD_BITS
+    _, z, v, _ = _tile_select(pkind, g, jnp.abs(g), u_ref[...], s1_ref[0, 0],
+                              s2_ref[0, 0], off_ref[1, i], up_ref[...],
+                              lo_ref[...])
+    rank = off_ref[0, i] + _tile_prefix(z, up_ref[...], lo_ref[...]) \
+        - z.astype(jnp.int32)
+    slot_ref[...] = jnp.where(z, rank, DROP_SLOT)
+    val_ref[...] = v
+    if res_ref:
+        # subtract what the wire carries (v rounded to the wire dtype), for
+        # ALL survivors — overflow-dropped ones were sampled, just not
+        # transmitted (documented fused-EF semantics)
+        sent = v.astype(wire_dtype).astype(jnp.float32)
+        res_ref[0][...] = (g - sent).astype(res_ref[0].dtype)
 
 
 def compact_emit_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
-                    scale: jax.Array, u_cod: jax.Array, *, pkind: str, codec,
-                    out_dtype, k_cap: int, d: int, rice_r: int = -1,
-                    ef: bool = False, interpret: bool = False):
-    """Pass 2 of the two-pass compaction: write the wire buffers directly.
+                    offsets: jax.Array, up: jax.Array, lo: jax.Array, *,
+                    pkind: str, wire_dtype=None, ef: bool = False,
+                    interpret: bool = False):
+    """Pass 2 of the two-pass compaction: re-derive the kept mask per tile
+    and give every survivor its global compact rank.
 
-    Emits ``(values[1, k_cap], idx[1, k_cap], rice_words, rice_used,
-    residual)`` where values are already codec-encoded (``out_dtype`` =
-    the codec's wire dtype), idx is the ascending-coordinate valid prefix,
-    and — when ``rice_r >= 0`` — ``rice_words[1, cap_words]`` /
-    ``used[1, 1]`` carry the Golomb-Rice index stream bit-packed in this
-    same pass, bit-identical to ``compaction.rice_encode`` on the emitted
-    buffers. ``ef=True`` additionally emits ``residual[r, c]`` (g minus
-    the wire values) per tile. ``rice_words``/``used``/``residual`` are
-    None when not requested.
-    """
+    ``offsets[0, t]`` / ``offsets[1, t]`` are the exclusive prefix sums of
+    pass 1's per-tile survivor / tie counts, formed outside the kernel, so
+    the tiles are independent. Emits ``(slot [r, c] int32, v [r, c] f32,
+    residual)``: ``slot`` is the compact rank of each survivor (DROP_SLOT
+    elsewhere) and ``v`` its amplified value; the compact ``(values, idx)``
+    buffers are one XLA scatter of these. ``ef=True`` additionally emits
+    ``residual [r, c]`` = g minus the survivors' values rounded to
+    ``wire_dtype`` (None when not requested)."""
     r, c = g.shape
     assert c == BLOCK_C, "two-pass kernels require the _pad_2d layout"
     grid = (r // BLOCK_R,)
-    rice = rice_r >= 0
-    cap_words = u_cap = 0
-    if rice:
-        from repro.comm.compaction import rice_cap_words
-        cap_words = rice_cap_words(k_cap, d, rice_r)
-        u_cap = cap_words * WORD_BITS - k_cap * rice_r
     s1_2 = jnp.asarray(s1, jnp.float32).reshape(1, 1)
     s2_2 = jnp.asarray(s2, jnp.float32).reshape(1, 1)
-    scale_2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    ucod_2 = jnp.asarray(u_cod, jnp.float32).reshape(1, -1)
-    smem = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
     tile = pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0))
-
-    def whole(n):
-        return pl.BlockSpec((1, n), lambda i: (0, 0))
-
-    in_specs = [tile, tile, smem, smem, smem, whole(ucod_2.shape[1])]
-    out_specs = [whole(k_cap), whole(k_cap), smem, smem, smem, smem]
-    out_shape = [jax.ShapeDtypeStruct((1, k_cap), out_dtype),
-                 jax.ShapeDtypeStruct((1, k_cap), jnp.int32)] + \
-                [jax.ShapeDtypeStruct((1, 1), jnp.int32)] * 4
-    if rice:
-        out_specs += [whole(cap_words), smem, whole(u_cap)]
-        out_shape += [jax.ShapeDtypeStruct((1, cap_words), jnp.int32),
-                      jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                      jax.ShapeDtypeStruct((1, u_cap), jnp.int32)]
+    out_specs = [tile, tile]
+    out_shape = [jax.ShapeDtypeStruct((r, c), jnp.int32),
+                 jax.ShapeDtypeStruct((r, c), jnp.float32)]
     if ef:
-        out_specs += [tile]
-        out_shape += [jax.ShapeDtypeStruct((r, c), g.dtype)]
-
-    body = functools.partial(
-        _compact_emit_body, pkind=pkind, codec=codec, k_cap=k_cap,
-        rice_r=rice_r, ef=ef, cap_words=cap_words, u_cap=u_cap,
-        t_last=grid[0] - 1)
+        out_specs.append(tile)
+        out_shape.append(jax.ShapeDtypeStruct((r, c), g.dtype))
     out = pl.pallas_call(
-        lambda *refs: body(refs),
+        functools.partial(_compact_emit_body, pkind=pkind,
+                          wire_dtype=wire_dtype or g.dtype),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[tile, tile, _smem(), _smem(),
+                  pl.BlockSpec((BLOCK_C, BLOCK_C), lambda i: (0, 0)),
+                  pl.BlockSpec((BLOCK_R, BLOCK_R), lambda i: (0, 0)),
+                  _smem((2, grid[0]))],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-        name=f"compact_emit_{pkind}_{codec.name}",
-    )(g, u, s1_2, s2_2, scale_2, ucod_2)
-    vals, idx = out[0][0], out[1][0]
-    pos = 6
-    rice_words = rice_used = residual = None
-    if rice:
-        rice_words = out[pos][0]
-        rice_used = out[pos + 1][0, 0]
-        pos += 3
-    if ef:
-        residual = out[pos]
-    return vals, idx, rice_words, rice_used, residual
+        name=f"compact_emit_{pkind}",
+    )(g, u, s1_2, s2_2, up, lo, offsets)
+    return out[0], out[1], (out[2] if ef else None)

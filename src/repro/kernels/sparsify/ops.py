@@ -6,15 +6,15 @@ fused: one stats pass, ``num_iters`` saturation-aware tail-stats passes
 driving the scalar rescale loop (skipped work when nothing saturates, since
 the rescale factor is exactly 1 then), and one threshold-sample-scale pass.
 
-The ``*_emit`` family is the two-pass compaction pipeline: the kernels'
-only large output is the wire buffer itself. Pass 1 (``select_stats_2d``)
-runs the selector and reduces survivor counts, p/variance accounting, and
-the codec-scale statistics in one traversal; pass 2 (``compact_emit_2d``)
-re-derives the kept mask and writes the compact ``(values, idx)`` buffers
-directly — values already codec-encoded (qsgd/ternary integer levels and
-bf16 emitted from the kernel exactly like f32), the optional EF residual
-in the same pass, and the Golomb-Rice index stream bit-packed on the way
-out (no post-kernel ``rice_encode``). One emit wrapper per selector:
+The ``*_emit`` family is the two-pass compaction pipeline. Pass 1
+(``select_stats_2d``) runs the selector and reduces per-tile survivor
+counts and the p/variance accounting in one traversal; their exclusive
+prefix sums are the tile offsets of pass 2 (``compact_emit_2d``), which
+re-derives the kept mask, ranks every survivor and emits the optional EF
+residual in the same pass. One XLA scatter of (rank, value) builds the
+compact ``(values, idx)`` buffers, and ``codec.encode`` runs on that
+compact buffer exactly as on the reference backend. One emit wrapper per
+selector:
 ``gspar_emit`` (Algorithm 3), ``closed_emit`` (Algorithm 2's lambda via
 one XLA sort, then the same fused sample+write), ``unisp_emit``,
 ``bern_emit``, ``topk_emit``. The legacy ``gspar_sparse(_ef)`` wrappers
@@ -145,20 +145,16 @@ class EmitResult(NamedTuple):
     ``values``/``idx`` are the compact buffers (values codec-encoded in the
     wire dtype, idx the ascending-coordinate valid prefix, padding slots
     idx 0 / value exactly 0). ``nnz`` counts survivors (pre-cap),
-    ``nonzeros`` the support |{i : g_i != 0}|, ``p_sum``/``den`` the
-    accounting reductions (sum p, sum g^2) that previously cost the
-    backend an extra O(d) pass. ``rice_words``/``rice_used`` carry the
-    pre-packed Golomb-Rice index stream when requested (else None);
-    ``residual`` the in-pass EF residual (else None)."""
+    ``p_sum``/``den`` the accounting reductions (sum p, sum g^2) that
+    would otherwise cost the backend an extra O(d) pass, ``scale`` the
+    codec's per-message scale, and ``residual`` the in-pass EF residual
+    (else None)."""
     values: jax.Array
     idx: jax.Array
     nnz: jax.Array
-    nonzeros: jax.Array
     p_sum: jax.Array
     den: jax.Array
     scale: jax.Array
-    rice_words: jax.Array | None
-    rice_used: jax.Array | None
     residual: jax.Array | None
 
 
@@ -166,39 +162,47 @@ _F32 = codecs_lib.FloatCodec()
 
 
 def _two_pass(flat: jax.Array, u: jax.Array | None, s1, s2, *, pkind: str,
-              codec, k_cap: int, rice_r: int, ef: bool,
-              u_cod: jax.Array | None, interpret: bool) -> EmitResult:
-    """Shared two-pass driver: pass 1 select+reduce, scale finalize, pass 2
-    compact write. ``u`` is the selector's pregenerated uniforms (ignored
-    for deterministic selectors), ``u_cod`` the codec's (length k_cap,
-    gathered per compact rank inside the kernel)."""
+              codec, k_cap: int, ef: bool, u_cod: jax.Array | None,
+              interpret: bool) -> EmitResult:
+    """Shared two-pass driver: pass 1 per-tile counts, exclusive tile
+    offsets, pass 2 ranks, then the compact scatter and the codec encode.
+    ``u`` is the selector's pregenerated uniforms (ignored for
+    deterministic selectors), ``u_cod`` the codec's (length k_cap, one per
+    compact rank)."""
     g2d, n, _, _ = _pad_2d(flat)
     if u is not None:
         u2d, _, _, _ = _pad_2d(u.reshape(-1).astype(jnp.float32))
     else:
         u2d = g2d                               # unused by the kernel body
-    cnt, nzc, psum, den, vsq, vmx = K.select_stats_2d(
-        g2d, u2d, s1, s2, k_cap=k_cap, pkind=pkind, interpret=interpret)
-    scale = codecs_lib.finalize_scale(codec, vsq, vmx)
-    uc = u_cod if u_cod is not None else jnp.zeros((1,), jnp.float32)
-    vals, idx, words, used, res = K.compact_emit_2d(
-        g2d, u2d, s1, s2, scale, uc, pkind=pkind, codec=codec,
-        out_dtype=codec.wire_dtype(flat.dtype), k_cap=k_cap, d=n,
-        rice_r=rice_r, ef=ef, interpret=interpret)
+    up, lo = K.prefix_operands()
+    tiles, psum, den = K.select_stats_2d(g2d, u2d, s1, s2, up, lo,
+                                         pkind=pkind, interpret=interpret)
+    offsets = jnp.cumsum(tiles, axis=1) - tiles          # exclusive
+    wire_dtype = codec.wire_dtype(flat.dtype)
+    slot, v, res = K.compact_emit_2d(g2d, u2d, s1, s2, offsets, up, lo,
+                                     pkind=pkind, wire_dtype=wire_dtype,
+                                     ef=ef, interpret=interpret)
+    # scatter from the kernels' 2-D layout: flattening slot first costs the
+    # TPU compiler a minute per vmapped group and buys nothing
+    vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
+    coord = (jax.lax.broadcasted_iota(jnp.int32, slot.shape, 0) * slot.shape[1]
+             + jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1))
+    idx = jnp.zeros((k_cap,), jnp.int32).at[slot].set(coord, mode="drop")
+    scale = codec.scale(vals)
+    values = codec.encode(vals, scale, u_cod).astype(wire_dtype)
     if ef:
         res = res.reshape(-1)[:n]
-    return EmitResult(vals, idx, cnt, nzc, psum, den, scale,
-                      words, used, res)
+    return EmitResult(values, idx, jnp.sum(tiles[0]), psum, den, scale, res)
 
 
-_EMIT_STATICS = ("k_cap", "codec", "rice_r", "ef", "interpret")
+_EMIT_STATICS = ("k_cap", "codec", "ef", "interpret")
 
 
 @functools.partial(jax.jit,
                    static_argnames=_EMIT_STATICS + ("rho", "num_iters"))
 def gspar_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
                k_cap: int, rho: float = 0.1, num_iters: int = 2,
-               codec=_F32, rice_r: int = -1, ef: bool = False,
+               codec=_F32, ef: bool = False,
                interpret: bool = False):
     """Algorithm 3 (greedy lambda), fully fused: stats -> scalar lambda ->
     two-pass compact emit. Returns ``(EmitResult, lam)``."""
@@ -208,39 +212,39 @@ def gspar_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
     lam = greedy_lambda(l1, mx, rho, n, num_iters,
                         tail_fn=_kernel_tail_fn(g2d, n, interpret))
     er = _two_pass(flat, u, lam, jnp.float32(0), pkind="lam", codec=codec,
-                   k_cap=k_cap, rice_r=rice_r, ef=ef, u_cod=u_cod,
+                   k_cap=k_cap, ef=ef, u_cod=u_cod,
                    interpret=interpret)
     return er, lam
 
 
 @functools.partial(jax.jit, static_argnames=_EMIT_STATICS + ("eps",))
 def closed_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
-                k_cap: int, eps: float = 0.1, codec=_F32, rice_r: int = -1,
-                ef: bool = False, interpret: bool = False):
+                k_cap: int, eps: float = 0.1, codec=_F32, ef: bool = False,
+                interpret: bool = False):
     """Algorithm 2 (closed-form lambda: one XLA sort for the scalar, shared
     with the reference solver bit-for-bit), then the same fused sample +
     compact write as the greedy path. Returns ``(EmitResult, lam)``."""
     flat = g.reshape(-1)
     lam, _any_ok = sparsify_lib.closed_form_lambda(flat, eps)
     er = _two_pass(flat, u, lam, jnp.float32(0), pkind="lam", codec=codec,
-                   k_cap=k_cap, rice_r=rice_r, ef=ef, u_cod=u_cod,
+                   k_cap=k_cap, ef=ef, u_cod=u_cod,
                    interpret=interpret)
     return er, lam
 
 
 @functools.partial(jax.jit, static_argnames=_EMIT_STATICS + ("rho",))
 def unisp_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
-               k_cap: int, rho: float = 0.1, codec=_F32, rice_r: int = -1,
-               ef: bool = False, interpret: bool = False):
+               k_cap: int, rho: float = 0.1, codec=_F32, ef: bool = False,
+               interpret: bool = False):
     """UniSp baseline: p = rho on the support. Returns an ``EmitResult``."""
     return _two_pass(g.reshape(-1), u, jnp.float32(rho), jnp.float32(0),
-                     pkind="rho", codec=codec, k_cap=k_cap, rice_r=rice_r,
-                     ef=ef, u_cod=u_cod, interpret=interpret)
+                     pkind="rho", codec=codec, k_cap=k_cap, ef=ef,
+                     u_cod=u_cod, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_EMIT_STATICS)
 def bern_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
-              k_cap: int, codec=_F32, rice_r: int = -1, ef: bool = False,
+              k_cap: int, codec=_F32, ef: bool = False,
               interpret: bool = False):
     """Bernoulli selector (TernGrad's): p = |g| / max|g|. Returns
     ``(EmitResult, max_abs)``."""
@@ -248,14 +252,14 @@ def bern_emit(g: jax.Array, u: jax.Array, u_cod: jax.Array | None = None, *,
     g2d, _, _, _ = _pad_2d(flat)
     _, mx = K.stats_l1max_2d(g2d, interpret=interpret)
     er = _two_pass(flat, u, jnp.float32(0), mx, pkind="bern", codec=codec,
-                   k_cap=k_cap, rice_r=rice_r, ef=ef, u_cod=u_cod,
+                   k_cap=k_cap, ef=ef, u_cod=u_cod,
                    interpret=interpret)
     return er, mx
 
 
 @functools.partial(jax.jit, static_argnames=_EMIT_STATICS + ("k_target",))
 def topk_emit(g: jax.Array, u_cod: jax.Array | None = None, *, k_cap: int,
-              k_target: int, codec=_F32, rice_r: int = -1, ef: bool = False,
+              k_target: int, codec=_F32, ef: bool = False,
               interpret: bool = False):
     """Deterministic top-k: one XLA ``top_k`` derives the magnitude
     threshold and the at-threshold tie budget; the kernel then keeps
@@ -270,7 +274,7 @@ def topk_emit(g: jax.Array, u_cod: jax.Array | None = None, *, k_cap: int,
     budget = jnp.float32(k_target) - (jnp.count_nonzero(topv > t)
                                       .astype(jnp.float32))
     return _two_pass(flat, None, t, budget, pkind="topk", codec=codec,
-                     k_cap=k_cap, rice_r=rice_r, ef=ef, u_cod=u_cod,
+                     k_cap=k_cap, ef=ef, u_cod=u_cod,
                      interpret=interpret)
 
 
@@ -349,23 +353,17 @@ def gspar_sparsify_prng(g: jax.Array, seed: jax.Array, rho: float = 0.1,
                         num_iters: int = 2, interpret: bool = False) -> jax.Array:
     """Production variant: on-core PRNG, no uniform input buffer.
 
-    interpret=True uses the TPU-interpret emulator (pltpu.InterpretParams)
-    when this jax ships it: the plain CPU interpreter has no lowering for the
-    TPU PRNG primitives. On older jax without the emulator we reproduce its
-    documented behaviour exactly — prng_random_bits yields zero bits off-TPU
-    (randomness is a hardware property), i.e. u == 0 and every coordinate
-    with p > 0 is kept — by running the uniform-input kernel with u = 0."""
+    interpret=True runs the kernel under the TPU-interpret emulator
+    (pltpu.InterpretParams): the plain CPU interpreter has no lowering for
+    the TPU PRNG primitives. The emulator's prng_random_bits yields zero
+    bits (randomness is a hardware property), i.e. u == 0 and every
+    coordinate with p > 0 is kept."""
     from jax.experimental.pallas import tpu as pltpu
     shape = g.shape
-    flat = g.reshape(-1)
-    g2d, n, _, _ = _pad_2d(flat)
+    g2d, n, _, _ = _pad_2d(g.reshape(-1))
     l1, mx = K.stats_l1max_2d(g2d, interpret=interpret)
     lam = greedy_lambda(l1, mx, rho, n, num_iters,
                         tail_fn=_kernel_tail_fn(g2d, n, interpret))
-    if interpret and not hasattr(pltpu, "InterpretParams"):
-        out = K.sparsify_2d(g2d, jnp.zeros_like(g2d, jnp.float32), lam,
-                            interpret=True)
-        return out.reshape(-1)[:n].reshape(shape)
     prng_interp = pltpu.InterpretParams() if interpret else False
     out = K.sparsify_prng_2d(g2d, lam, seed, interpret=prng_interp)
     return out.reshape(-1)[:n].reshape(shape)

@@ -3,17 +3,25 @@
     PYTHONPATH=src python -m repro.launch.train --arch gemma2-9b --smoke \
         --steps 50 --compressor gspar --rho 0.05 --wire gather
 
-On real hardware the full config + production mesh is selected automatically;
-on this CPU container use --smoke (reduced config, single device) or set
-XLA_FLAGS=--xla_force_host_platform_device_count=N --mesh NxM for a fake
-multi-device run.
+With 256 or more devices the full config and the production mesh are
+selected automatically. ``--chip`` selects the arch's one-chip config
+(published widths; depth and vocabulary cut to one chip's share), and
+``--smoke`` a reduced variant for the CPU, where
+XLA_FLAGS=--xla_force_host_platform_device_count=N --mesh NxM fakes a
+multi-device run. ``build`` returns the job exactly as ``main`` runs it, so
+other drivers (``chip_smoke.py``) step the same compiled program.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 import time
+from typing import Callable
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpoint
 from repro.configs import registry
@@ -27,10 +35,14 @@ from repro.optim.optimizers import adam, init_control, init_feedback, sgd
 from repro.train import step as step_lib
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced CPU-sized variant")
+    ap.add_argument("--chip", action="store_true",
+                    help="the arch's one-chip config: published widths, "
+                         "depth and vocabulary cut to one chip's share")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -103,8 +115,50 @@ def main(argv=None):
     ap.add_argument("--mode", default=None, choices=[None, "compressed", "fsdp"])
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed place. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing is
+    changed; otherwise the cache lives in ``.jax_cache/`` at the root of the
+    checkout (a fixed path: the path is part of the cache key). Call from a
+    program's entry point, before the first compile, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    return str(path)
+
+
+@dataclasses.dataclass
+class Job:
+    """One training run as ``main`` builds it: the model and compression
+    configs, the mesh, the jitted step ``(*state, batch, key) -> (*state,
+    metrics)`` with the state donated, and ``init_state`` making that state
+    (params, optimizer, then the EF residual and control state when on)
+    afresh from the fixed seed, placed where the step keeps it.
+    ``batch_sharding`` places each token batch (None: left to jit)."""
+    cfg: tf.ModelConfig
+    comp: CompressionConfig
+    mesh: jax.sharding.Mesh
+    train_step: Callable
+    init_state: Callable[[], tuple]
+    batch: int
+    seq: int
+    batch_sharding: jax.sharding.Sharding | None = None
+
+    def step_inputs(self, key: jax.Array):
+        """The data loop's key schedule: ``(next key, batch, step key)``."""
+        key, k_data, k_q = jax.random.split(key, 3)
+        batch = token_batch(k_data, self.cfg.vocab, self.batch, self.seq)
+        if self.batch_sharding is not None:
+            batch = jax.device_put(batch, self.batch_sharding)
+        return key, batch, k_q
+
+
+def build(args: argparse.Namespace) -> Job:
     if args.xla_preset != "none":
         # before the first backend touch (jax.devices() below inits XLA)
         from repro.comm.xla_flags import apply as apply_xla_preset
@@ -112,13 +166,16 @@ def main(argv=None):
         print(f"xla_preset={args.xla_preset}: {len(applied)} flag(s)")
 
     spec = registry.get(args.arch)
-    cfg = spec.smoke if args.smoke else spec.model
+    if args.chip and spec.chip is None:
+        raise SystemExit(f"{args.arch} has no one-chip config")
+    cfg = (spec.smoke if args.smoke else spec.chip if args.chip
+           else spec.model)
     n_dev = len(jax.devices())
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         mesh = make_mesh(shape, ("data", "model")[:len(shape)] if len(shape) < 3
                          else ("pod", "data", "model"))
-    elif not args.smoke and n_dev >= 256:
+    elif not (args.smoke or args.chip) and n_dev >= 256:
         mesh = make_production_mesh(multi_pod=(n_dev >= 512))
     else:
         mesh = make_mesh((n_dev, 1), ("data", "model"))
@@ -132,13 +189,11 @@ def main(argv=None):
 
     print(f"arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} mode={mode}")
-
-    params, _ = split_params(tf.init_model(jax.random.key(0), cfg))
-    n_params = sum(p.size for p in jax.tree.leaves(params))
-    print(f"params: {n_params / 1e6:.1f}M")
+    if args.chip:
+        for cut, note in spec.reduced.items():
+            print(f"reduced {cut}: {note}")
 
     opt = (adam(args.lr) if args.optimizer == "adam" else sgd(args.lr))
-    opt_state = opt.init(params)
     comp = CompressionConfig(name=args.compressor, codec=args.codec,
                              qsgd_bits=args.qsgd_bits, rho=args.rho,
                              wire=args.wire, wire_layout=args.wire_layout,
@@ -155,36 +210,36 @@ def main(argv=None):
                              rice_fitted=args.rice_fitted,
                              min_leaf_size=1024)
     print(f"compression: {comp.describe()}")
-    ef_state = None
-    if comp.error_feedback:
-        # compressed mode: stacked per-worker residual (plus the per-pod
-        # one when the pod stage recompresses); fsdp: params-shaped
+    if comp.adaptive and mode != "compressed":
+        raise SystemExit("--adaptive requires the compressed train mode")
+    workers = step_lib.mesh_workers(mesh, multi_pod)
+
+    def init_state() -> tuple:
+        params, _ = split_params(tf.init_model(jax.random.key(0), cfg))
+        state = (params, opt.init(params))
+        if comp.error_feedback:
+            # compressed mode: stacked per-worker residual (plus the per-pod
+            # one when the pod stage recompresses); fsdp: params-shaped
+            if mode == "compressed":
+                sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+                num_pods = (sizes["pod"]
+                            if multi_pod and comp.resparsify_pods else None)
+                state += (init_feedback(params, workers, num_pods=num_pods),)
+            else:
+                state += (init_feedback(params),)
+        if comp.adaptive:
+            state += (init_control(params, workers),)
         if mode == "compressed":
-            sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            num_pods = (sizes["pod"]
-                        if multi_pod and comp.resparsify_pods else None)
-            ef_state = init_feedback(params,
-                                     step_lib.mesh_workers(mesh, multi_pod),
-                                     num_pods=num_pods)
-        else:
-            ef_state = init_feedback(params)
-    ctl_state = None
-    if comp.adaptive:
-        if mode != "compressed":
-            raise SystemExit("--adaptive requires the compressed train mode")
-        ctl_state = init_control(params,
-                                 step_lib.mesh_workers(mesh, multi_pod))
+            state = jax.device_put(state, step_lib.compressed_state_shardings(
+                mesh, state, multi_pod))
+        return state
+
     with jax.set_mesh(mesh):
-        # Donate params/opt_state (and the EF residual, which the grouped
-        # compression path consumes into fresh stacked buffers) — the train
-        # loop rebinds all of them every step, so XLA can reuse their HBM
-        # for the step's outputs instead of holding both copies live.
-        if ctl_state is not None:
-            donate = (0, 1, 2, 3)
-        elif ef_state is not None:
-            donate = (0, 1, 2)
-        else:
-            donate = (0, 1)
+        # Donate the whole state (the EF residual too, which the grouped
+        # compression path consumes into fresh stacked buffers): the train
+        # loop rebinds all of it every step, so XLA can reuse its HBM for
+        # the step's outputs instead of holding both copies live.
+        donate = tuple(range(2 + comp.error_feedback + comp.adaptive))
         if mode == "compressed":
             train_step = jax.jit(step_lib.make_compressed_train_step(
                 cfg, comp, opt, mesh, rules, multi_pod=multi_pod),
@@ -192,21 +247,29 @@ def main(argv=None):
         else:
             train_step = jax.jit(step_lib.make_fsdp_train_step(
                 cfg, comp, opt, mesh, rules), donate_argnums=donate)
+    batch_sharding = None
+    if mode == "compressed":
+        # the step's manual worker axes split the batch
+        batch_sharding = NamedSharding(
+            mesh, P(("pod", "data") if multi_pod else "data"))
+    return Job(cfg=cfg, comp=comp, mesh=mesh, train_step=train_step,
+               init_state=init_state, batch=args.batch, seq=args.seq,
+               batch_sharding=batch_sharding)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
+    job = build(args)
+    state = job.init_state()
+    n_params = sum(p.size for p in jax.tree.leaves(state[0]))
+    print(f"params: {n_params / 1e6:.1f}M")
+    with jax.set_mesh(job.mesh):
         key = jax.random.key(1)
         t0 = time.time()
         for step_i in range(args.steps):
-            key, k_data, k_q = jax.random.split(key, 3)
-            batch = token_batch(k_data, cfg.vocab, args.batch, args.seq)
-            if ctl_state is not None:
-                params, opt_state, ef_state, ctl_state, metrics = train_step(
-                    params, opt_state, ef_state, ctl_state, batch, k_q)
-            elif ef_state is not None:
-                params, opt_state, ef_state, metrics = train_step(
-                    params, opt_state, ef_state, batch, k_q)
-            else:
-                params, opt_state, metrics = train_step(params, opt_state,
-                                                        batch, k_q)
+            key, batch, k_q = job.step_inputs(key)
+            *state, metrics = job.train_step(*state, batch, k_q)
             if step_i % args.log_every == 0 or step_i == args.steps - 1:
                 m = {k: float(v) for k, v in metrics.items()}
                 msg = (f"step {step_i:>5} loss {m['loss']:.4f}")
@@ -215,7 +278,7 @@ def main(argv=None):
                             f" var x{m['var_ratio']:.2f}"
                             f" msg_bits {m['bits']:.3g}"
                             f" (dense {m['dense_bits']:.3g})")
-                if ctl_state is not None:
+                if job.comp.adaptive:
                     msg += f" skipped {m.get('skipped', 0.0):.1f}"
                 print(msg, flush=True)
         dt = time.time() - t0
@@ -223,19 +286,19 @@ def main(argv=None):
               f"({args.steps / dt:.2f} steps/s)")
 
     if args.checkpoint:
-        tree = {"params": params, "opt": opt_state}
-        if ef_state is not None:
+        names = ["params", "opt"]
+        if job.comp.error_feedback:
             # the EF residual is training state: restarting without it
             # re-biases the first compressed step after restore
-            tree["ef"] = ef_state
-        if ctl_state is not None:
+            names.append("ef")
+        if job.comp.adaptive:
             # ditto the control state: dropping it resets delta coding to a
             # cold full send and re-primes the skip bounds
-            tree["ctl"] = ctl_state
-        checkpoint.save(args.checkpoint, tree,
+            names.append("ctl")
+        checkpoint.save(args.checkpoint, dict(zip(names, state)),
                         extra={"arch": args.arch, "steps": args.steps,
-                               "error_feedback": bool(ef_state is not None),
-                               "adaptive": bool(ctl_state is not None)})
+                               "error_feedback": job.comp.error_feedback,
+                               "adaptive": job.comp.adaptive})
         print(f"checkpoint -> {args.checkpoint}")
     return 0
 

@@ -25,7 +25,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm.sync import sync_tree
 from repro.core.api import CompressionConfig, compress_tree
@@ -104,6 +104,34 @@ def init_compressed_control(cfg: transformer.ModelConfig,
                                jax.random.key(0))
     vals, _ = split_params(param_sds)
     return init_control(vals, num_workers=mesh_workers(mesh, multi_pod))
+
+
+def compressed_state_shardings(mesh, state: tuple,
+                               multi_pod: bool = False) -> tuple:
+    """Where the compressed step keeps its state between steps: params,
+    optimizer state and every params-shaped tree replicated; the stacked
+    per-worker trees (residual, last_sent, bound) split over the worker
+    axes on their leading axis; the pod residual over ``pod``. Placing the
+    first step's inputs so means every step runs one compiled program."""
+    manual = ("pod", "data") if multi_pod else ("data",)
+    rep = NamedSharding(mesh, P())
+    worker = NamedSharding(mesh, P(manual if multi_pod else manual[0]))
+
+    def like(tree, sharding):
+        return jax.tree.map(lambda _: sharding, tree)
+
+    out = [like(state[0], rep), like(state[1], rep)]
+    for s in state[2:]:
+        if isinstance(s, FeedbackState):
+            pod = (None if s.pod_residual is None
+                   else like(s.pod_residual, NamedSharding(mesh, P("pod"))))
+            out.append(FeedbackState(residual=like(s.residual, worker),
+                                     pod_residual=pod))
+        else:
+            out.append(ControlState(last_sent=like(s.last_sent, worker),
+                                    last_avg=like(s.last_avg, rep),
+                                    bound=like(s.bound, worker), step=rep))
+    return tuple(out)
 
 
 def make_compressed_train_step(cfg: transformer.ModelConfig,
@@ -326,7 +354,10 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
         var_scale = jnp.maximum(stats.var_ratio, 1.0) if var_adaptive_lr else 1.0
         new_params, new_opt = opt.update(grads, opt_state, params,
                                          var_scale=var_scale)
-        metrics = {"loss": loss, "bits": stats.bits, "density": stats.density,
+        grad_sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                      for g in jax.tree.leaves(grads))
+        metrics = {"loss": loss, "grad_norm": jnp.sqrt(grad_sq),
+                   "bits": stats.bits, "density": stats.density,
                    "var_ratio": stats.var_ratio, "wire_bytes": stats.wire_bytes,
                    "wire_bytes_intra": stats.wire_bytes_intra,
                    "wire_bytes_inter": stats.wire_bytes_inter,

@@ -440,7 +440,7 @@ class TestGroupedDispatch:
         key discipline (per-leaf split, per-layer split when stacked)."""
         from repro.core.grouping import leaf_rows
         from repro.core.sparse import resolve_backend
-        backend = resolve_backend(cfg.backend, cfg.kernel_interpret)
+        backend = resolve_backend(cfg.backend)
         leaves, treedef = jax.tree_util.tree_flatten(grads)
         stk = jax.tree_util.tree_flatten(stacked)[0]
         keys = jax.random.split(key, len(leaves))

@@ -310,23 +310,30 @@ class TestEmitCodecFusion:
 
 
 class TestEmitRicePacking:
-    """Pass 2's fused Golomb-Rice index packing must be bit-identical to
-    the send-side ``compaction.rice_encode`` it retires."""
+    """The emit's coordinate-ordered valid prefix lets the RICE layout pack
+    without a sort (``rice_encode(nnz=...)``, what ``wire_layout.pack``
+    runs for ``idx_sorted`` producers). That sort-free stream must be
+    bit-identical to the generic argsort path on the same buffers."""
 
     def _check(self, g, k_cap, rho, r):
         from repro.comm import compaction
         n = g.shape[0]
         u = jax.random.uniform(jax.random.key(41), (n,), jnp.float32)
-        er, _ = ops.gspar_emit(g, u, k_cap=k_cap, rho=rho, rice_r=r,
-                               interpret=True)
+        er, _ = ops.gspar_emit(g, u, k_cap=k_cap, rho=rho, interpret=True)
         sv, words, used = compaction.rice_encode(er.values, er.idx, n, r,
                                                  nnz=er.nnz)
-        np.testing.assert_array_equal(np.asarray(er.rice_words),
-                                      np.asarray(words))
-        assert int(er.rice_used) == int(used)
+        gsv, gwords, gused = compaction.rice_encode(er.values, er.idx, n, r)
+        np.testing.assert_array_equal(np.asarray(words), np.asarray(gwords))
+        assert int(used) == int(gused)
+        np.testing.assert_array_equal(np.asarray(sv, np.float32),
+                                      np.asarray(gsv, np.float32))
         # idx_sorted producer: coordinate-ordered values are the buffer
         np.testing.assert_array_equal(np.asarray(sv, np.float32),
                                       np.asarray(er.values, np.float32))
+        dec = compaction.rice_decode(words, k_cap, n, r)
+        live = min(int(er.nnz), k_cap)
+        np.testing.assert_array_equal(np.asarray(dec)[:live],
+                                      np.asarray(er.idx)[:live])
 
     def test_words_bit_identical_to_rice_encode(self):
         from repro.core import coding
@@ -430,22 +437,20 @@ class TestPRNGVariant:
         assert abs(nnz - expected) < 5 * sd + 1e-6, (nnz, expected, sd)
 
     def test_on_core_prng_density_within_binomial_bounds(self):
-        """Same binomial-bounds check for the on-core PRNG production path
-        (ROADMAP open item). Off-TPU without the TPU-interpret emulator the
-        hardware PRNG yields zero bits by construction, so the path cannot
-        be validated statistically — skip with the reason on record rather
-        than assert something vacuous."""
-        from jax.experimental.pallas import tpu as pltpu
-        on_tpu = jax.default_backend() == "tpu"
-        if not on_tpu and not hasattr(pltpu, "InterpretParams"):
+        """Same binomial-bounds check for the on-core PRNG production path.
+        Off-TPU the TPU-interpret emulator's prng_random_bits yields zero
+        bits (randomness is a hardware property), so every p > 0
+        coordinate is kept and the path cannot be validated statistically:
+        skip with the reason on record rather than assert something
+        vacuous. ``chip_smoke.py`` prints the same check from the chip."""
+        if jax.default_backend() != "tpu":
             pytest.skip(
                 "on-core PRNG (pltpu.prng_random_bits) yields zero random "
-                "bits off-TPU and this jax lacks the TPU-interpret emulator "
-                "(pltpu.InterpretParams); run on TPU to validate density")
+                "bits off-TPU under the TPU-interpret emulator; the density "
+                "check only means something on a TPU")
         n, rho = 1 << 16, 0.05
         g = _grad(26, (n,), jnp.float32)
-        q = ops.gspar_sparsify_prng(g, jnp.int32(1234), rho=rho,
-                                    interpret=not on_tpu)
+        q = ops.gspar_sparsify_prng(g, jnp.int32(1234), rho=rho)
         a = np.abs(np.asarray(g))
         lam = _np_greedy_lambda(a, rho, num_iters=2)
         p = np.minimum(lam * a, 1.0)

@@ -163,6 +163,89 @@ class TestRiceCodecEdgeCases:
             assert int(used[layer]) <= compaction.rice_cap_words(k_cap, d, r)
 
 
+def _bit_pack_gaps(x, r, cap_words):
+    """Bit-level reference of ``compaction._rice_pack_gaps``: one element
+    per stream bit, packed 32 to a word."""
+    k = x.shape[0]
+    q = x >> r
+    if r > 0:
+        rp = np.arange(k * r)
+        rbits = (x[rp // r] >> (rp % r)) & 1
+    else:
+        rbits = np.zeros(0, np.int64)
+    u_cap = cap_words * 32 - k * r
+    tpos = np.cumsum(q) + np.arange(k)
+    total = int(q.sum()) + k
+    tmark = np.zeros(u_cap, bool)
+    tmark[tpos[tpos < u_cap]] = True
+    ubits = (np.arange(u_cap) < total) & ~tmark
+    bits = np.concatenate([rbits, ubits]).astype(np.uint64)
+    words = (bits.reshape(-1, 32) << np.arange(32, dtype=np.uint64)).sum(1)
+    return (words.astype(np.uint32).view(np.int32),
+            (k * r + total + 31) // 32)
+
+
+def _bit_decode(words, k, r):
+    """Bit-level reference of ``compaction.rice_decode`` for one message:
+    the i-th code's terminator is the (i+1)-th zero bit of the unary
+    field (``u_cap`` when the message holds fewer zeros)."""
+    u = np.asarray(words).view(np.uint32).astype(np.uint64)
+    bits = ((u[:, None] >> np.arange(32, dtype=np.uint64)) & 1).reshape(-1)
+    rem = ((bits[:k * r].reshape(k, r) << np.arange(r, dtype=np.uint64))
+           .sum(1).astype(np.int64) if r else np.zeros(k, np.int64))
+    ub = bits[k * r:]
+    z = np.flatnonzero(ub == 0)
+    zpos = np.full(k, ub.size, np.int64)
+    zpos[:min(k, z.size)] = z[:k]
+    q = zpos - np.concatenate([[-1], zpos[:-1]]) - 1
+    # int32 wrap-around, as the decoder's own arithmetic
+    return (np.cumsum(((q << r) | rem) + 1) - 1).astype(np.int32)
+
+
+class TestWordLevelCodec:
+    """The word-level Rice pack and decode against bit-level references
+    that follow the stream layout one bit at a time."""
+
+    pack = staticmethod(jax.jit(compaction._rice_pack_gaps,
+                                static_argnums=(1, 2)))
+    decode = staticmethod(jax.jit(compaction.rice_decode,
+                                  static_argnums=(1, 2, 3)))
+
+    @pytest.mark.parametrize("d,k_cap,r", [(70, 64, 0), (1000, 128, 2),
+                                           (4096, 256, 3), (1 << 16, 640, 6),
+                                           (5000, 128, 30)])
+    def test_pack_matches_bit_level(self, d, k_cap, r):
+        rng = np.random.default_rng(d + r)
+        for n_live in (0, 1, k_cap // 3, k_cap):
+            coords = np.sort(rng.choice(d, min(n_live, d), replace=False))
+            sidx = np.full(k_cap, d, np.int32)
+            sidx[:coords.size] = coords
+            x = np.asarray(compaction._rice_gaps(jnp.asarray(sidx), d))
+            cap = compaction.rice_cap_words(k_cap, d, r)
+            words, used = self.pack(jnp.asarray(x), r, cap)
+            ref_words, ref_used = _bit_pack_gaps(x.astype(np.int64), r, cap)
+            np.testing.assert_array_equal(np.asarray(words), ref_words)
+            assert int(used) == ref_used
+
+    @pytest.mark.parametrize("k_cap,r,n_words", [(64, 0, 9), (100, 3, 31),
+                                                 (37, 5, 12), (8, 30, 9)])
+    def test_decode_matches_bit_level_on_any_words(self, k_cap, r, n_words):
+        """Random words (valid or not, too few zeros included) decode as
+        the bit-level reference does, batched and unbatched."""
+        rng = np.random.default_rng(k_cap * 31 + r)
+        words = rng.integers(-2**31, 2**31, (3, 2, n_words), dtype=np.int64)
+        words[0, 0] = -1                         # no zero bit at all
+        words[0, 1, :n_words // 2] = 0           # a run of zero words
+        words = words.astype(np.int32)
+        got = np.asarray(self.decode(jnp.asarray(words), k_cap, 0, r))
+        for b in np.ndindex(words.shape[:-1]):
+            np.testing.assert_array_equal(got[b],
+                                          _bit_decode(words[b], k_cap, r),
+                                          err_msg=str(b))
+        one = np.asarray(self.decode(jnp.asarray(words[1, 1]), k_cap, 0, r))
+        np.testing.assert_array_equal(one, got[1, 1])
+
+
 class TestRealizedEqualsModel:
     def test_encoder_words_match_coding_model(self):
         """Property sweep: the encoder's used-word count == the coding
@@ -348,7 +431,6 @@ class TestRiceOnTheWire:
         from dist_harness import run_with_devices
         out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
-import repro  # noqa: F401  (jax compat shims)
 from jax.sharding import PartitionSpec as P
 from repro.core.api import CompressionConfig
 from repro.comm.sync import sync_tree
