@@ -1,0 +1,9 @@
+"""Tests of the benchmark's own code, on the CPU at small sizes:
+``pytest chipbench`` from the repository root."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
