@@ -10,11 +10,12 @@ makes the job exactly as ``python -m repro.launch.train --arch gemma-2b
 would, and this script compiles and steps it. Weights are random from the
 launcher's fixed seed. It prints, line by line: the config, compile
 seconds, the device memory the compiled step needs, whether the emit
-kernels are compiled into the step, loss and seconds per step, a rerun's
+kernels are compiled into the step, the loss of each step, a rerun's
 losses, ``peak_bytes_in_use``, the pallas-vs-reference agreement on one
 real-width leaf, and the on-core PRNG density. The last line of standard
 output is one JSON object, ``{"ok": true, "device": {...}}``, printed only
-when every check passed.
+when every check passed. It times no step: step time and tokens per
+second on the chip come from the benchmark, ``chipbench/run.py``.
 
 With ``--four-chips`` it runs only the data-parallel phase: the same step
 on a 4x1 (data, model) mesh with the sparse ``gather`` wire beside the
@@ -29,7 +30,6 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
-import statistics
 import sys
 import time
 
@@ -82,23 +82,18 @@ def compile_job(train, argv: list):
 
 def run_steps(job, compiled, n: int):
     """Fresh state from the seed, then ``n`` steps through the compiled
-    program. Returns (losses, synced grad norms, seconds per step, final
-    state)."""
+    program. Returns (losses, synced grad norms, final state)."""
     import jax
     state = job.init_state()
     key = jax.random.key(1)
-    losses, norms, secs = [], [], []
+    losses, norms = [], []
     with jax.set_mesh(job.mesh):
         for _ in range(n):
             key, batch, k_q = job.step_inputs(key)
-            jax.block_until_ready((state, batch))
-            t0 = time.perf_counter()
             *state, metrics = compiled(*state, batch, k_q)
-            jax.block_until_ready((state, metrics))
-            secs.append(time.perf_counter() - t0)
             losses.append(float(metrics["loss"]))
             norms.append(float(metrics["grad_norm"]))
-    return losses, norms, secs, state
+    return losses, norms, state
 
 
 def leaf_agreement(check, cfg_kw: dict, shape: tuple):
@@ -222,18 +217,14 @@ def one_chip(check, train):
     check(n_calls > 0 and all(k in hlo for k in KERNELS),
           "the compiled step holds the compiled emit kernels")
 
-    losses, _, step_s, state = run_steps(job, compiled, STEPS)
-    tokens = job.batch * job.seq
-    for i, (loss, s) in enumerate(zip(losses, step_s)):
-        print(f"step {i} loss {loss!r} {s:.4f} s", flush=True)
-    steady = statistics.median(step_s[1:])
-    print(f"steady step {steady:.4f} s ({tokens / steady:.0f} tokens/s, "
-          f"median of steps 1-{STEPS - 1})", flush=True)
+    losses, _, state = run_steps(job, compiled, STEPS)
+    for i, loss in enumerate(losses):
+        print(f"step {i} loss {loss!r}", flush=True)
     check(all(np.isfinite(losses)), "losses finite")
     n_params = sum(x.size for x in jax.tree.leaves(state[0]))
     print(f"params {n_params / 1e6:.1f}M", flush=True)
     del state
-    rerun, _, _, state = run_steps(job, compiled, STEPS)
+    rerun, _, state = run_steps(job, compiled, STEPS)
     del state
     print(f"rerun losses {rerun}", flush=True)
     check(rerun == losses, "a rerun from the same seed gives the same losses")
@@ -261,14 +252,14 @@ def four_chips(check, train):
         job, compiled, secs = compile_job(
             train, _launcher_argv(wire, "pallas", "4x1", 4))
         print(f"{wire}: compile {secs:.2f} s", flush=True)
-        losses, norms, step_s, state = run_steps(job, compiled, 2)
+        losses, norms, state = run_steps(job, compiled, 2)
         for d in jax.devices():
             used = (d.memory_stats() or {}).get("bytes_in_use", 0)
             print(f"{wire}: device {d.id} bytes_in_use {used} ({_gib(used)})",
                   flush=True)
-        for i, (loss, norm, s) in enumerate(zip(losses, norms, step_s)):
-            print(f"{wire}: step {i} loss {loss!r} synced grad_norm {norm!r} "
-                  f"{s:.4f} s", flush=True)
+        for i, (loss, norm) in enumerate(zip(losses, norms)):
+            print(f"{wire}: step {i} loss {loss!r} synced grad_norm {norm!r}",
+                  flush=True)
         check(all(np.isfinite(losses + norms)), f"{wire}: losses finite")
         runs[wire] = (losses, norms, jax.tree.map(np.asarray, state[0]))
         del state, compiled, job

@@ -119,6 +119,7 @@ from repro.core.api import (CompressionConfig, compress_tree,
                             compress_tree_sparse)
 from repro.core.grouping import chunk_spans, member_row_flags
 from repro.core.sparse import SparseGrad
+from repro.core.stages import stage
 from repro.optim.optimizers import ControlState, FeedbackState
 
 Axis = str | tuple[str, ...]
@@ -155,7 +156,8 @@ def _worker_key(key: jax.Array, axes: tuple[str, ...]) -> jax.Array:
 
 
 def _sync_leaves_dense(q_tree: Any, axis: Axis):
-    synced = jax.tree.map(lambda q: jax.lax.pmean(q, axis), q_tree)
+    with stage("exchange"):
+        synced = jax.tree.map(lambda q: jax.lax.pmean(q, axis), q_tree)
     wire = sum(float(q.size * q.dtype.itemsize) for q in jax.tree.leaves(q_tree))
     return synced, wire
 
@@ -201,8 +203,9 @@ def _compact_items(cfg: CompressionConfig, leaves: list, stk_leaves: list):
         stack = (stack_parts[0] if len(stack_parts) == 1
                  else jnp.concatenate(stack_parts))
         def _compact_encode(row, _k_cap=grp.k_cap):
-            vals, idx, nnz = compaction.compact(row, _k_cap)
-            vals, scale = _encode_det(codec, vals)
+            with stage("compact"):
+                vals, idx, nnz = compaction.compact(row, _k_cap)
+                vals, scale = _encode_det(codec, vals)
             return vals, idx, nnz, scale
         vals, idx, nnz, scale = (
             jax.vmap(_compact_encode)(stack) if batched
@@ -284,7 +287,8 @@ def _apply_skip(cfg: CompressionConfig, items: list, skip_flags: list):
         itemsize = jnp.dtype(sg.values.dtype).itemsize
         sg2 = dataclasses.replace(sg, values=vals)
         if lp.layout == "rice":
-            v2d, w2d, nw = wire_layout.pack(sg2, lp)
+            with stage("pack"):
+                v2d, w2d, nw = wire_layout.pack(sg2, lp)
             w2d = jnp.where(mask[:, None], 0, w2d)
             nw = jnp.where(mask, 0, nw)
             sg2 = dataclasses.replace(sg2, values=v2d, rice_words=w2d,
@@ -384,16 +388,19 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
         # low-precision leaves, and the accounting charges what the HLO
         # collective actually moves (4 bytes/element). The payloads are
         # already concatenated per group; member runs slice them back.
-        flat = jnp.concatenate(
-            [items[e][1].reshape(-1).astype(jnp.float32) for e in dense_ids])
-        synced = jax.lax.pmean(flat, axis)
+        with stage("pack"):
+            flat = jnp.concatenate([items[e][1].reshape(-1)
+                                    .astype(jnp.float32) for e in dense_ids])
+        with stage("exchange"):
+            synced = jax.lax.pmean(flat, axis)
         off = 0
-        for e in dense_ids:
-            for i, n in items[e][2]:
-                leaf = leaves[i]
-                out[i] = (synced[off:off + n].reshape(leaf.shape)
-                          .astype(leaf.dtype))
-                off += n
+        with stage("apply"):
+            for e in dense_ids:
+                for i, n in items[e][2]:
+                    leaf = leaves[i]
+                    out[i] = (synced[off:off + n].reshape(leaf.shape)
+                              .astype(leaf.dtype))
+                    off += n
         wire += float(flat.size * 4)
 
     cap = min(cfg.bucket_coord_cap, compaction.INT32_COORD_LIMIT)
@@ -401,14 +408,15 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
         # pack every item ONCE (chunks row-slice the shared streams), then
         # split the bucket's row blocks into capacity-bounded chunks
         packed: dict = {}
-        for e in ids:
-            sg = items[e][1]
-            lp = wire_layout.plan(sg, fitted=cfg.rice_fitted)
-            # [L, val_len], [L, idx_len], [L] realized rice words
-            packed[e] = (lp,) + wire_layout.pack(sg, lp) + (
-                jnp.asarray(sg.scale, jnp.float32).reshape(-1)
-                if codec.has_scale else None,)
-            overflow = overflow + jnp.sum(sg.overflow())
+        with stage("pack"):
+            for e in ids:
+                sg = items[e][1]
+                lp = wire_layout.plan(sg, fitted=cfg.rice_fitted)
+                # [L, val_len], [L, idx_len], [L] realized rice words
+                packed[e] = (lp,) + wire_layout.pack(sg, lp) + (
+                    jnp.asarray(sg.scale, jnp.float32).reshape(-1)
+                    if codec.has_scale else None,)
+                overflow = overflow + jnp.sum(sg.overflow())
         chunks = chunk_spans([(e, packed[e][0].layers, packed[e][0].d)
                               for e in ids], cap)
         pieces: dict = {}                # leaf id -> flat row-order pieces
@@ -422,34 +430,35 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
             i_off = 0                    #  self-description
             s_off = 0
             c_off = 0
-            for e, r0, n in chunk:
-                lp0, v2d, w2d, nw, sflat = packed[e]
-                lp = dataclasses.replace(lp0, layers=n)
-                w2 = w2d[r0:r0 + n]
-                if lp.layout == "coo":
-                    # only coordinate lists get the chunk offset (rebased
-                    # per chunk); bitmap/rice words are opaque bit payload
-                    # and dense runs ship no index
-                    w2 = (w2 + (jnp.arange(n, dtype=jnp.int32)
-                                * lp.d)[:, None] + jnp.int32(coord_off))
-                if lp.idx_len:
-                    widx_parts.append(w2.reshape(-1))
-                if lp.layout == "rice":
-                    count_parts.append(nw[r0:r0 + n])
-                else:
-                    static_idx_words += n * lp.idx_len
-                vals_parts.append(v2d[r0:r0 + n].reshape(-1))
-                if codec.has_scale:
-                    slot_parts.append(
-                        jnp.repeat(jnp.arange(n, dtype=jnp.int32),
-                                   lp.val_len) + jnp.int32(s_off))
-                    scale_parts.append(sflat[r0:r0 + n])
-                plans.append((e, lp, r0, v_off, i_off, coord_off, c_off))
-                v_off += n * lp.val_len
-                i_off += n * lp.idx_len
-                coord_off += lp.block
-                s_off += n
-                c_off += n if lp.layout == "rice" else 0
+            with stage("pack"):
+                for e, r0, n in chunk:
+                    lp0, v2d, w2d, nw, sflat = packed[e]
+                    lp = dataclasses.replace(lp0, layers=n)
+                    w2 = w2d[r0:r0 + n]
+                    if lp.layout == "coo":
+                        # only coordinate lists get the chunk offset (rebased
+                        # per chunk); bitmap/rice words are opaque bit payload
+                        # and dense runs ship no index
+                        w2 = (w2 + (jnp.arange(n, dtype=jnp.int32)
+                                    * lp.d)[:, None] + jnp.int32(coord_off))
+                    if lp.idx_len:
+                        widx_parts.append(w2.reshape(-1))
+                    if lp.layout == "rice":
+                        count_parts.append(nw[r0:r0 + n])
+                    else:
+                        static_idx_words += n * lp.idx_len
+                    vals_parts.append(v2d[r0:r0 + n].reshape(-1))
+                    if codec.has_scale:
+                        slot_parts.append(
+                            jnp.repeat(jnp.arange(n, dtype=jnp.int32),
+                                       lp.val_len) + jnp.int32(s_off))
+                        scale_parts.append(sflat[r0:r0 + n])
+                    plans.append((e, lp, r0, v_off, i_off, coord_off, c_off))
+                    v_off += n * lp.val_len
+                    i_off += n * lp.idx_len
+                    coord_off += lp.block
+                    s_off += n
+                    c_off += n if lp.layout == "rice" else 0
             # the chunker bounded this by construction; a trip here means a
             # caller fed spans wider than the cap past it
             compaction.check_bucket_coords(coord_off, len(chunk))
@@ -459,9 +468,11 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
                 # ragged collective sizes its receives from exactly this
                 # vector; the static-shape emulation below uses it to zero
                 # payload padding pre-decode and to price realized bytes.
-                counts_flat = jnp.concatenate(count_parts)       # [R]
-                gcounts = jax.lax.all_gather(counts_flat, axis,
-                                             tiled=False)        # [m, R]
+                with stage("pack"):
+                    counts_flat = jnp.concatenate(count_parts)   # [R]
+                with stage("exchange"):
+                    gcounts = jax.lax.all_gather(counts_flat, axis,
+                                                 tiled=False)    # [m, R]
                 wire += float(counts_flat.size * 4)              # the vector
                 # fitted counts carry the parameter header in their high
                 # bits (wire-format v4); only the used-word field is
@@ -471,15 +482,20 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
                     & compaction.RICE_HDR_USED_MASK).astype(jnp.float32)
             else:
                 gcounts = None
-            vals_flat = jnp.concatenate(vals_parts)
-            gvals = jax.lax.all_gather(vals_flat, axis, tiled=False)  # [m, V]
+            with stage("pack"):
+                vals_flat = jnp.concatenate(vals_parts)
+            with stage("exchange"):
+                gvals = jax.lax.all_gather(vals_flat, axis,
+                                           tiled=False)           # [m, V]
             if widx_parts:
                 # phase two: the index/word payload at its static shape —
                 # for RICE segments only the true encoded words (charged
                 # above) are protocol bytes, the rest is zero padding
-                widx_flat = jnp.concatenate(widx_parts)
-                gwidx = jax.lax.all_gather(widx_flat, axis,
-                                           tiled=False)           # [m, I]
+                with stage("pack"):
+                    widx_flat = jnp.concatenate(widx_parts)
+                with stage("exchange"):
+                    gwidx = jax.lax.all_gather(widx_flat, axis,
+                                               tiled=False)       # [m, I]
                 wire += float(static_idx_words * 4)
             else:
                 gwidx = None             # every leaf elided its index stream
@@ -487,35 +503,42 @@ def _bucketed_sync(items: list, leaves: list, axis: Axis,
                 # per-message scales ride a third (tiny: one f32 per
                 # leaf/layer) all_gather; each slot decodes with its own
                 # worker's scale.
-                scales_flat = jnp.concatenate(scale_parts)       # [S]
-                slot_map = jnp.concatenate(slot_parts)           # [V]
-                gscales = jax.lax.all_gather(scales_flat, axis,
-                                             tiled=False)        # [m, S]
-                decoded = codec.decode(gvals, gscales[:, slot_map])
+                with stage("pack"):
+                    scales_flat = jnp.concatenate(scale_parts)   # [S]
+                    slot_map = jnp.concatenate(slot_parts)       # [V]
+                with stage("exchange"):
+                    gscales = jax.lax.all_gather(scales_flat, axis,
+                                                 tiled=False)    # [m, S]
+                with stage("decode"):
+                    decoded = codec.decode(gvals, gscales[:, slot_map])
                 wire += float(scales_flat.size * 4)
             else:
-                decoded = gvals.astype(jnp.float32)
+                with stage("decode"):
+                    decoded = gvals.astype(jnp.float32)
             upd_parts, coord_parts = [], []
-            for (e, lp, r0, v0, i0, c0, cc0) in plans:
-                dv = decoded[:, v0:v0 + lp.layers * lp.val_len]
-                wseg = (gwidx[:, i0:i0 + lp.layers * lp.idx_len]
-                        if lp.idx_len else None)
-                wcnt = (gcounts[:, cc0:cc0 + lp.layers]
-                        if lp.layout == "rice" else None)
-                upd, crd = wire_layout.unpack_gathered(lp, dv, wseg, c0,
-                                                       wcounts=wcnt)
-                upd_parts.append(upd)
-                coord_parts.append(crd)
-            dense = jnp.zeros((coord_off,), jnp.float32)
-            dense = dense.at[
-                jnp.concatenate(coord_parts, axis=1).reshape(-1)].add(
-                jnp.concatenate(upd_parts, axis=1).reshape(-1),
-                mode="drop") / m
-            for (e, lp, r0, _, _, c0, _) in plans:
-                _route_span(items[e][2], r0, lp.layers, lp.d,
-                            dense[c0:c0 + lp.block], pieces)
+            with stage("decode"):
+                for (e, lp, r0, v0, i0, c0, cc0) in plans:
+                    dv = decoded[:, v0:v0 + lp.layers * lp.val_len]
+                    wseg = (gwidx[:, i0:i0 + lp.layers * lp.idx_len]
+                            if lp.idx_len else None)
+                    wcnt = (gcounts[:, cc0:cc0 + lp.layers]
+                            if lp.layout == "rice" else None)
+                    upd, crd = wire_layout.unpack_gathered(lp, dv, wseg, c0,
+                                                           wcounts=wcnt)
+                    upd_parts.append(upd)
+                    coord_parts.append(crd)
+            with stage("apply"):
+                dense = jnp.zeros((coord_off,), jnp.float32)
+                dense = dense.at[
+                    jnp.concatenate(coord_parts, axis=1).reshape(-1)].add(
+                    jnp.concatenate(upd_parts, axis=1).reshape(-1),
+                    mode="drop") / m
+                for (e, lp, r0, _, _, c0, _) in plans:
+                    _route_span(items[e][2], r0, lp.layers, lp.d,
+                                dense[c0:c0 + lp.block], pieces)
             wire += float(v_off) * wdt.itemsize
-        _assemble_pieces(pieces, leaves, out)
+        with stage("apply"):
+            _assemble_pieces(pieces, leaves, out)
 
     return out, wire, overflow
 
@@ -634,80 +657,85 @@ def _overlapped_sync(items: list, leaves: list, axis: Axis,
         cur_parts, cur_vparts, cur_segs = [], [], []
         cur_words = cur_velems = cur_coords = 0
 
-    for i in reversed(sparse_ids):
-        sg = items[i][1]
-        lp0 = wire_layout.plan(sg, fitted=cfg.rice_fitted)
-        wdt = jnp.dtype(sg.values.dtype)
-        v2d_full, w2d_full, nw_full = wire_layout.pack(sg, lp0)
-        overflow = overflow + jnp.sum(sg.overflow())
-        for (_, r0, n) in (s for c in chunk_spans([(i, lp0.layers, lp0.d)],
-                                                  cap) for s in c):
-            lp = dataclasses.replace(lp0, layers=n)
-            w2d = w2d_full[r0:r0 + n]
-            v2d = v2d_full[r0:r0 + n]
-            parts = []
-            if lp.layout == "rice":
-                nw = nw_full[r0:r0 + n]
-                parts.append(nw.reshape(-1))                   # counts header
-                wire += float(n * 4)
-                # mask off the fitted-parameter header bits (identity on
-                # static-format counts) — only used words are payload
-                wire = wire + 4.0 * jnp.sum(
-                    nw & compaction.RICE_HDR_USED_MASK).astype(jnp.float32)
-            else:
-                wire += float(n * lp.idx_len * 4)
-            if lp.idx_len:
-                if lp.layout == "coo":
-                    # layer strides only: coordinates are span-block-local
-                    w2d = w2d + (jnp.arange(n, dtype=jnp.int32)
-                                 * lp.d)[:, None]
-                parts.append(w2d.reshape(-1))
-            n_vals = n * lp.val_len
-            if wdt.itemsize == 4:
-                vwords, velems0 = _words_of(n_vals, wdt), -1
-                parts.append(_word_pack(v2d))
-            else:
-                vwords, velems0 = 0, cur_velems
-            wire += float(n_vals) * wdt.itemsize
-            if codec.has_scale:
-                parts.append(_word_pack(
-                    jnp.asarray(sg.scale, jnp.float32).reshape(-1)[r0:r0 + n]))
-                wire += float(n * 4)
-            n_words = sum(p.shape[0] for p in parts)
-            n_bytes = n_words * 4 + (0 if vwords else n_vals * wdt.itemsize)
-            if (cur_words or cur_velems) and \
-                    (cur_words * 4 + cur_velems * wdt.itemsize + n_bytes
-                     > cap_bytes
-                     or cur_coords + lp.block > cap):
-                flush()
-                velems0 = min(velems0, 0)              # offset in new bucket
-            cur_segs.append((i, lp, r0, cur_words, vwords, wdt, velems0))
-            cur_parts.extend(parts)
-            cur_words += n_words
-            cur_coords += lp.block
-            if not vwords:
-                cur_vparts.append(v2d.reshape(-1))
-                cur_velems += n_vals
-    flush()
+    with stage("pack"):
+        for i in reversed(sparse_ids):
+            sg = items[i][1]
+            lp0 = wire_layout.plan(sg, fitted=cfg.rice_fitted)
+            wdt = jnp.dtype(sg.values.dtype)
+            v2d_full, w2d_full, nw_full = wire_layout.pack(sg, lp0)
+            overflow = overflow + jnp.sum(sg.overflow())
+            for (_, r0, n) in (s for c in chunk_spans([(i, lp0.layers, lp0.d)],
+                                                      cap) for s in c):
+                lp = dataclasses.replace(lp0, layers=n)
+                w2d = w2d_full[r0:r0 + n]
+                v2d = v2d_full[r0:r0 + n]
+                parts = []
+                if lp.layout == "rice":
+                    nw = nw_full[r0:r0 + n]
+                    parts.append(nw.reshape(-1))                   # counts header
+                    wire += float(n * 4)
+                    # mask off the fitted-parameter header bits (identity on
+                    # static-format counts) — only used words are payload
+                    wire = wire + 4.0 * jnp.sum(
+                        nw & compaction.RICE_HDR_USED_MASK).astype(jnp.float32)
+                else:
+                    wire += float(n * lp.idx_len * 4)
+                if lp.idx_len:
+                    if lp.layout == "coo":
+                        # layer strides only: coordinates are span-block-local
+                        w2d = w2d + (jnp.arange(n, dtype=jnp.int32)
+                                     * lp.d)[:, None]
+                    parts.append(w2d.reshape(-1))
+                n_vals = n * lp.val_len
+                if wdt.itemsize == 4:
+                    vwords, velems0 = _words_of(n_vals, wdt), -1
+                    parts.append(_word_pack(v2d))
+                else:
+                    vwords, velems0 = 0, cur_velems
+                wire += float(n_vals) * wdt.itemsize
+                if codec.has_scale:
+                    parts.append(_word_pack(
+                        jnp.asarray(sg.scale, jnp.float32).reshape(-1)[r0:r0 + n]))
+                    wire += float(n * 4)
+                n_words = sum(p.shape[0] for p in parts)
+                n_bytes = n_words * 4 + (0 if vwords else n_vals * wdt.itemsize)
+                if (cur_words or cur_velems) and \
+                        (cur_words * 4 + cur_velems * wdt.itemsize + n_bytes
+                         > cap_bytes
+                         or cur_coords + lp.block > cap):
+                    flush()
+                    velems0 = min(velems0, 0)              # offset in new bucket
+                cur_segs.append((i, lp, r0, cur_words, vwords, wdt, velems0))
+                cur_parts.extend(parts)
+                cur_words += n_words
+                cur_coords += lp.block
+                if not vwords:
+                    cur_vparts.append(v2d.reshape(-1))
+                    cur_velems += n_vals
+        flush()
 
-    pending = [(segs, jax.lax.all_gather(stream, axis, tiled=False),
-                None if vstream is None
-                else jax.lax.all_gather(vstream, axis, tiled=False))
-               for segs, stream, vstream in buckets]
+    with stage("exchange"):
+        pending = [(segs, jax.lax.all_gather(stream, axis, tiled=False),
+                    None if vstream is None
+                    else jax.lax.all_gather(vstream, axis, tiled=False))
+                   for segs, stream, vstream in buckets]
 
     if dense_ids:
         # tiny-leaf psum, issued after the sparse buckets so the sparse
         # collectives lead the schedule; f32 like _bucketed_sync
-        flat = jnp.concatenate(
-            [items[e][1].reshape(-1).astype(jnp.float32) for e in dense_ids])
-        synced = jax.lax.pmean(flat, axis)
+        with stage("pack"):
+            flat = jnp.concatenate([items[e][1].reshape(-1)
+                                    .astype(jnp.float32) for e in dense_ids])
+        with stage("exchange"):
+            synced = jax.lax.pmean(flat, axis)
         off = 0
-        for e in dense_ids:
-            for i, n in items[e][2]:
-                leaf = leaves[i]
-                out[i] = (synced[off:off + n].reshape(leaf.shape)
-                          .astype(leaf.dtype))
-                off += n
+        with stage("apply"):
+            for e in dense_ids:
+                for i, n in items[e][2]:
+                    leaf = leaves[i]
+                    out[i] = (synced[off:off + n].reshape(leaf.shape)
+                              .astype(leaf.dtype))
+                    off += n
         wire += float(flat.size * 4)
 
     # --- consume, same order the buckets were issued --------------------
@@ -723,56 +751,59 @@ def _overlapped_sync(items: list, leaves: list, axis: Axis,
                                        len(segs))
         upd_parts, coord_parts = [], []
         block_off = 0
-        # scale-free codecs: one bucket-wide cast of the companion value
-        # stream (sync casts its whole value buffer once too) — per-leaf
-        # casts of sub-word dtypes cost XLA CPU a pass per leaf
-        gvf = (gv.astype(jnp.float32)
-               if gv is not None and not codec.has_scale else None)
-        for (i, lp, r0, w0, vwords, wdt, velems0) in segs:
-            pos = w0
-            wcnt = wseg = None
-            if lp.layout == "rice":
-                wcnt = gs[:, pos:pos + lp.layers]
-                pos += lp.layers
-            if lp.idx_len:
-                wseg = gs[:, pos:pos + lp.layers * lp.idx_len]
-                pos += lp.layers * lp.idx_len
-            n_vals = lp.layers * lp.val_len
-            if vwords:
-                enc = _word_unpack(gs[:, pos:pos + vwords], wdt, n_vals)
-                pos += vwords
-            else:       # companion stream, native dtype — plain slice
-                enc = (gvf if gvf is not None
-                       else gv)[:, velems0:velems0 + n_vals]
-            if codec.has_scale:
-                scales = _word_unpack(gs[:, pos:pos + lp.layers],
-                                      jnp.float32, lp.layers)
-                # per-(worker, layer) scale broadcast over the layer's
-                # value slots — elementwise, so bitwise the same decode
-                # as sync's slot_map expansion
-                decoded = codec.decode(
-                    enc.reshape(m, lp.layers, lp.val_len),
-                    scales[:, :, None]).reshape(m, -1)
-            else:
-                decoded = enc.astype(jnp.float32)
-            upd, crd = wire_layout.unpack_gathered(lp, decoded, wseg,
-                                                   block_off, wcounts=wcnt)
-            if lp.layout == "coo":
-                # coo coords come straight off the wire (span-local)
-                crd = crd + jnp.int32(block_off)
-            upd_parts.append(upd)
-            coord_parts.append(crd)
-            block_off += lp.block
-        dense = jnp.zeros((block_off,), jnp.float32)
-        dense = dense.at[
-            jnp.concatenate(coord_parts, axis=1).reshape(-1)].add(
-            jnp.concatenate(upd_parts, axis=1).reshape(-1), mode="drop") / m
-        off = 0
-        for (e, lp, r0, _, _, _, _) in segs:
-            _route_span(items[e][2], r0, lp.layers, lp.d,
-                        dense[off:off + lp.block], pieces)
-            off += lp.block
-    _assemble_pieces(pieces, leaves, out)
+        with stage("decode"):
+            # scale-free codecs: one bucket-wide cast of the companion value
+            # stream (sync casts its whole value buffer once too) — per-leaf
+            # casts of sub-word dtypes cost XLA CPU a pass per leaf
+            gvf = (gv.astype(jnp.float32)
+                   if gv is not None and not codec.has_scale else None)
+            for (i, lp, r0, w0, vwords, wdt, velems0) in segs:
+                pos = w0
+                wcnt = wseg = None
+                if lp.layout == "rice":
+                    wcnt = gs[:, pos:pos + lp.layers]
+                    pos += lp.layers
+                if lp.idx_len:
+                    wseg = gs[:, pos:pos + lp.layers * lp.idx_len]
+                    pos += lp.layers * lp.idx_len
+                n_vals = lp.layers * lp.val_len
+                if vwords:
+                    enc = _word_unpack(gs[:, pos:pos + vwords], wdt, n_vals)
+                    pos += vwords
+                else:       # companion stream, native dtype — plain slice
+                    enc = (gvf if gvf is not None
+                           else gv)[:, velems0:velems0 + n_vals]
+                if codec.has_scale:
+                    scales = _word_unpack(gs[:, pos:pos + lp.layers],
+                                          jnp.float32, lp.layers)
+                    # per-(worker, layer) scale broadcast over the layer's
+                    # value slots — elementwise, so bitwise the same decode
+                    # as sync's slot_map expansion
+                    decoded = codec.decode(
+                        enc.reshape(m, lp.layers, lp.val_len),
+                        scales[:, :, None]).reshape(m, -1)
+                else:
+                    decoded = enc.astype(jnp.float32)
+                upd, crd = wire_layout.unpack_gathered(lp, decoded, wseg,
+                                                       block_off, wcounts=wcnt)
+                if lp.layout == "coo":
+                    # coo coords come straight off the wire (span-local)
+                    crd = crd + jnp.int32(block_off)
+                upd_parts.append(upd)
+                coord_parts.append(crd)
+                block_off += lp.block
+        with stage("apply"):
+            dense = jnp.zeros((block_off,), jnp.float32)
+            dense = dense.at[
+                jnp.concatenate(coord_parts, axis=1).reshape(-1)].add(
+                jnp.concatenate(upd_parts, axis=1).reshape(-1), mode="drop") / m
+            off = 0
+            for (e, lp, r0, _, _, _, _) in segs:
+                _route_span(items[e][2], r0, lp.layers, lp.d,
+                            dense[off:off + lp.block], pieces)
+                off += lp.block
+    with stage("apply"):
+        _assemble_pieces(pieces, leaves, out)
 
     return out, wire, overflow
 
@@ -894,7 +925,8 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
             "CompressionConfig(adaptive=True, error_feedback=True) or drop "
             "the control argument.")
 
-    worker_key = _worker_key(key, key_axes)
+    with stage("compress"):
+        worker_key = _worker_key(key, key_axes)
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     stk_leaves = (jax.tree_util.tree_flatten(stacked)[0]
@@ -948,9 +980,11 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
 
     wire_inter = 0.0
     if cfg.wire == "dense":
-        q_tree, new_res, stats = compress_tree(cfg, worker_key, send_grads,
-                                               residual=residual,
-                                               stacked=stacked)
+        with stage("compress"):
+            q_tree, new_res, stats = compress_tree(cfg, worker_key,
+                                                   send_grads,
+                                                   residual=residual,
+                                                   stacked=stacked)
         if cfg.adaptive:
             # skipped leaves contribute exact zeros to the psum — the dense
             # twin of the sparse wire's masked rows
@@ -964,10 +998,10 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
             # split stays honest: intra = data-axis stage, inter = pod stage
             synced, wire_inter = _sync_leaves_dense(synced, pod_axis)
     else:   # gather | packed (validated at CompressionConfig construction)
-        items, new_res, _, stats = compress_tree_sparse(cfg, worker_key,
-                                                        send_grads,
-                                                        stacked=stacked,
-                                                        residual=residual)
+        with stage("compress"):
+            items, new_res, _, stats = compress_tree_sparse(
+                cfg, worker_key, send_grads, stacked=stacked,
+                residual=residual)
         skip_savings = None
         if cfg.adaptive:
             items, skip_savings = _apply_skip(cfg, items, skip_flags)
@@ -995,28 +1029,29 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
             # only reachable with resparsify_pods: the plain dense pod
             # stage already ran in the intra/inter split above
             pod_key = _pod_key(key, key_axes, data_axes)
-            if cfg.error_feedback:
-                synced, new_pod_res, _ = compress_tree(
-                    cfg, pod_key, synced, stacked=stacked,
-                    residual=pod_residual)
-            else:
-                synced, _, _ = compress_tree(cfg, pod_key, synced,
-                                             stacked=stacked)
-            synced, wire_inter = _sync_leaves_dense(synced, pod_axis)
-        else:
-            synced_leaves = jax.tree_util.tree_flatten(synced)[0]
-            if cfg.resparsify_pods:
-                pod_key = _pod_key(key, key_axes, data_axes)
+            with stage("compress"):
                 if cfg.error_feedback:
-                    items2, new_pod_res, _, _ = compress_tree_sparse(
+                    synced, new_pod_res, _ = compress_tree(
                         cfg, pod_key, synced, stacked=stacked,
                         residual=pod_residual)
                 else:
-                    items2, _, _, _ = compress_tree_sparse(cfg, pod_key,
-                                                           synced,
-                                                           stacked=stacked)
-            else:
-                items2 = _compact_items(cfg, synced_leaves, stk_leaves)
+                    synced, _, _ = compress_tree(cfg, pod_key, synced,
+                                                 stacked=stacked)
+            synced, wire_inter = _sync_leaves_dense(synced, pod_axis)
+        else:
+            synced_leaves = jax.tree_util.tree_flatten(synced)[0]
+            with stage("compress"):
+                if cfg.resparsify_pods:
+                    pod_key = _pod_key(key, key_axes, data_axes)
+                    if cfg.error_feedback:
+                        items2, new_pod_res, _, _ = compress_tree_sparse(
+                            cfg, pod_key, synced, stacked=stacked,
+                            residual=pod_residual)
+                    else:
+                        items2, _, _, _ = compress_tree_sparse(
+                            cfg, pod_key, synced, stacked=stacked)
+                else:
+                    items2 = _compact_items(cfg, synced_leaves, stk_leaves)
             if not cfg.resparsify_pods:
                 if cfg.error_feedback:
                     # the pod-union of the data-axis workers' coordinates
@@ -1026,10 +1061,11 @@ def sync_tree(cfg: CompressionConfig, key: jax.Array, grads: Any,
                     # worker of the pod carries the same drop, so the next
                     # intra-pod mean reinstates it — exactly the 1/P global
                     # weight the dense pod stage would have given it)
-                    drops = _compaction_drops(items2, synced_leaves)
-                    new_res = jax.tree.map(
-                        lambda r, d: r + d, new_res,
-                        jax.tree_util.tree_unflatten(treedef, drops))
+                    with stage("compress"):
+                        drops = _compaction_drops(items2, synced_leaves)
+                        new_res = jax.tree.map(
+                            lambda r, d: r + d, new_res,
+                            jax.tree_util.tree_unflatten(treedef, drops))
             out_leaves, wire_inter, ovf2 = _exchange_fn(cfg)(
                 items2, synced_leaves, pod_axis, cfg)
             synced = jax.tree_util.tree_unflatten(treedef, out_leaves)

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from repro.comm import compaction
 from repro.core import codecs as codecs_lib
 from repro.core import coding
+from repro.core.stages import stage
 
 
 def _ones_scale():
@@ -209,11 +210,13 @@ class ReferenceBackend:
             # compaction.
             return self._topk_fast(cfg, scheme, g, k_cap)
         q, p, wire, scale = scheme.apply_dense(key, g)
-        vals, idx, nnz = compaction.compact(q, k_cap)
-        # wire values at the selected coordinates: encode and selection
-        # commute (the codec is elementwise given the scale), and padding
-        # slots point at zero-magnitude coords whose encoded level is 0.
-        wire_vals = wire.reshape(-1)[idx]
+        with stage("compact"):
+            vals, idx, nnz = compaction.compact(q, k_cap)
+            # wire values at the selected coordinates: encode and selection
+            # commute (the codec is elementwise given the scale), and
+            # padding slots point at zero-magnitude coords whose encoded
+            # level is 0.
+            wire_vals = wire.reshape(-1)[idx]
         bits = scheme.message_bits(q, p, g.size)
         from repro.core._compressors import finish_compressed
         cg = finish_compressed(g, q, p, bits)

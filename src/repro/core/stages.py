@@ -1,0 +1,33 @@
+"""The stages of the compressed train step, named inside the program.
+
+Each stage's work is traced under ``jax.named_scope("stage.<name>")``, so
+every HLO instruction it lowers to carries the name in its ``op_name``
+metadata (``jit(step)/.../stage.apply/scatter-add``; the backward pass as
+``transpose(jvp(stage.model))/...``). A profiler trace names its device
+events by instruction, so each event's device time can be put under the
+stage that issued it. Scopes nest: the innermost stage names the
+instruction (``compact`` runs inside ``compress``). A scope changes only
+metadata: the compiled program is the same with or without it.
+
+  model      forward and backward pass
+  compress   uniform draws, selection kernels, lambda, error-feedback residual
+  compact    the compact write of the selected coordinates and its codec encode
+  pack       wire layout encode: bitmap / Golomb-Rice words, coordinate order
+  exchange   the collectives
+  decode     value decode and wire layout decode of the gathered buffers
+  apply      scatter-add of the gathered buffers into the averaged gradient
+  optimizer  the optimizer update
+"""
+from __future__ import annotations
+
+import jax
+
+STAGES = ("model", "compress", "compact", "pack", "exchange", "decode",
+          "apply", "optimizer")
+
+
+def stage(name: str):
+    """``jax.named_scope`` of one stage of ``STAGES``."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; have {STAGES}")
+    return jax.named_scope("stage." + name)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.core import codecs as codecs_lib
 from repro.core import sparsify as sparsify_lib
+from repro.core.stages import stage
 from repro.kernels.sparsify import kernel as K
 
 
@@ -179,17 +180,19 @@ def _two_pass(flat: jax.Array, u: jax.Array | None, s1, s2, *, pkind: str,
                                          pkind=pkind, interpret=interpret)
     offsets = jnp.cumsum(tiles, axis=1) - tiles          # exclusive
     wire_dtype = codec.wire_dtype(flat.dtype)
-    slot, v, res = K.compact_emit_2d(g2d, u2d, s1, s2, offsets, up, lo,
-                                     pkind=pkind, wire_dtype=wire_dtype,
-                                     ef=ef, interpret=interpret)
-    # scatter from the kernels' 2-D layout: flattening slot first costs the
-    # TPU compiler a minute per vmapped group and buys nothing
-    vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
-    coord = (jax.lax.broadcasted_iota(jnp.int32, slot.shape, 0) * slot.shape[1]
-             + jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1))
-    idx = jnp.zeros((k_cap,), jnp.int32).at[slot].set(coord, mode="drop")
-    scale = codec.scale(vals)
-    values = codec.encode(vals, scale, u_cod).astype(wire_dtype)
+    with stage("compact"):
+        slot, v, res = K.compact_emit_2d(g2d, u2d, s1, s2, offsets, up, lo,
+                                         pkind=pkind, wire_dtype=wire_dtype,
+                                         ef=ef, interpret=interpret)
+        # scatter from the kernels' 2-D layout: flattening slot first costs
+        # the TPU compiler a minute per vmapped group and buys nothing
+        vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
+        coord = (jax.lax.broadcasted_iota(jnp.int32, slot.shape, 0)
+                 * slot.shape[1]
+                 + jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1))
+        idx = jnp.zeros((k_cap,), jnp.int32).at[slot].set(coord, mode="drop")
+        scale = codec.scale(vals)
+        values = codec.encode(vals, scale, u_cod).astype(wire_dtype)
     if ef:
         res = res.reshape(-1)[:n]
     return EmitResult(values, idx, jnp.sum(tiles[0]), psum, den, scale, res)

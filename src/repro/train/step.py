@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm.sync import sync_tree
 from repro.core.api import CompressionConfig, compress_tree
+from repro.core.stages import stage
 from repro.dist import sharding as shd
 from repro.models import transformer
 from repro.models.common import split_params
@@ -214,9 +215,10 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
         is_leaf=lambda t: isinstance(t, P))
 
     def grad_fn(params, batch):
-        with shd.activation_sharding(inner_rules, mesh):
+        with stage("model"), shd.activation_sharding(inner_rules, mesh):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        loss = jax.lax.pmean(loss, manual)
+        with stage("exchange"):
+            loss = jax.lax.pmean(loss, manual)
         return loss, jax.tree.map(lambda g: g[None], grads)
 
     # out_specs of a partial-manual region may only name ITS manual axes;
@@ -234,6 +236,7 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
     ef = comp.error_feedback
     hier_ef = ef and comp.resparsify_pods and multi_pod
 
+    @stage("exchange")
     def _reduce_stats(stats):
         if shard_local_sync:
             # each model shard sends its own message: totals sum, ratios avg
@@ -352,8 +355,9 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
 
     def _finish(loss, grads, stats, opt_state, params):
         var_scale = jnp.maximum(stats.var_ratio, 1.0) if var_adaptive_lr else 1.0
-        new_params, new_opt = opt.update(grads, opt_state, params,
-                                         var_scale=var_scale)
+        with stage("optimizer"):
+            new_params, new_opt = opt.update(grads, opt_state, params,
+                                             var_scale=var_scale)
         grad_sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                       for g in jax.tree.leaves(grads))
         metrics = {"loss": loss, "grad_norm": jnp.sqrt(grad_sq),
@@ -449,28 +453,33 @@ def make_fsdp_train_step(cfg: transformer.ModelConfig,
     ef = comp is not None and comp.name != "none" and comp.error_feedback
 
     def _grads(params, batch):
-        with shd.activation_sharding(rules, mesh):
+        with stage("model"), shd.activation_sharding(rules, mesh):
             return jax.value_and_grad(loss_fn)(params, batch)
 
     def train_step(params, opt_state, batch, key):
         loss, grads = _grads(params, batch)
         metrics = {"loss": loss}
         if comp is not None and comp.name != "none":
-            q_tree, _, stats = compress_tree(comp, key, grads, stacked=stacked)
+            with stage("compress"):
+                q_tree, _, stats = compress_tree(comp, key, grads,
+                                                 stacked=stacked)
             grads = q_tree
             metrics.update(bits=stats.bits, density=stats.density,
                            var_ratio=stats.var_ratio)
-        new_params, new_opt = opt.update(grads, opt_state, params)
+        with stage("optimizer"):
+            new_params, new_opt = opt.update(grads, opt_state, params)
         return new_params, new_opt, metrics
 
     def train_step_ef(params, opt_state, ef_state, batch, key):
         loss, grads = _grads(params, batch)
-        q_tree, new_res, stats = compress_tree(comp, key, grads,
-                                               residual=ef_state.residual,
-                                               stacked=stacked)
+        with stage("compress"):
+            q_tree, new_res, stats = compress_tree(
+                comp, key, grads, residual=ef_state.residual,
+                stacked=stacked)
         metrics = {"loss": loss, "bits": stats.bits, "density": stats.density,
                    "var_ratio": stats.var_ratio}
-        new_params, new_opt = opt.update(q_tree, opt_state, params)
+        with stage("optimizer"):
+            new_params, new_opt = opt.update(q_tree, opt_state, params)
         return (new_params, new_opt, FeedbackState(residual=new_res),
                 metrics)
 
