@@ -181,7 +181,7 @@ def _unpack_bits(words: jax.Array) -> jax.Array:
 # holds the k_cap quotients as q one-bits followed by a 0 terminator each —
 # and because NO remainder bits live there, every 0-bit in the unary region
 # is a terminator: the i-th code's quotient falls out of the positions of
-# the first k_cap zero bits (a cumsum rank + one scatter), with no
+# the first k_cap zero bits (a rank histogram and prefix scans), with no
 # sequential walk over code boundaries. Encoded length is data-dependent
 # (the realized wire cost) but every buffer shape is static: the word
 # capacity bounds any possible stream (rice_cap_words), and padding is
@@ -357,17 +357,19 @@ def rice_decode_fitted(words: jax.Array, k_cap: int, d: int,
 
 def rice_decode(words: jax.Array, k_cap: int, d: int, r: int) -> jax.Array:
     """Decoded coordinate stream of a Rice-coded message: ``words
-    [..., W]`` (int32 code words) -> ``idx [..., k_cap]`` (int32, stream
-    order = ascending coordinate order — aligned with the coordinate-
-    ordered value buffer). Slots past the live count decode to whatever
-    the tail's zero-quotient codes cumsum to; the receiver must mask them
-    by their zero value (repro.comm.wire_layout.unpack_gathered does).
-    Batch dims are supported; everything is fixed-shape.
+    [..., W]`` (int32 code words, W * 32 >= k_cap * r) -> ``idx [...,
+    k_cap]`` (int32, stream order = ascending coordinate order — aligned
+    with the coordinate-ordered value buffer). Slots past the live count
+    decode to whatever the tail's zero-quotient codes cumsum to; the
+    receiver must mask them by their zero value
+    (repro.comm.wire_layout.unpack_gathered does). Batch dims are
+    supported; everything is fixed-shape.
 
-    Word-level throughout, with every gather one-dimensional: the batch
-    dims are folded into a flat word index. A per-bit array, or a gather
-    batched over [workers, layers], would carry a minor axis of 32 bits or
-    of 2-3 index components that TPU tiling pads to 128 lanes.
+    Word-level throughout, with no gather and every scatter
+    one-dimensional: the batch dims are folded into a flat slot index. A
+    per-bit array, or a scatter batched over [workers, layers], would
+    carry a minor axis of 32 bits or of 2-3 index components that TPU
+    tiling pads to 128 lanes.
     """
     batch = words.shape[:-1]
     n_words = words.shape[-1]
@@ -375,63 +377,91 @@ def rice_decode(words: jax.Array, k_cap: int, d: int, r: int) -> jax.Array:
     for b in batch:
         rows *= b
     u = jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(rows, n_words)
-    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
-    flat = u.reshape(-1)
-    if r > 0:
-        pos = jnp.arange(k_cap, dtype=jnp.int32) * r
-        word, off = pos >> 5, (pos & 31).astype(jnp.uint32)
-        lo = jnp.take(flat, row * n_words + word) >> off
-        nxt = jnp.take(flat, row * n_words + jnp.minimum(word + 1,
-                                                         n_words - 1))
-        hi = jnp.where(off + r > WORD_BITS, nxt << ((WORD_BITS - off) & 31),
-                       jnp.uint32(0))
-        rem = ((lo | hi) & jnp.uint32((1 << r) - 1)).astype(jnp.int32)
-    else:
-        rem = jnp.zeros((rows, k_cap), jnp.int32)
     # the unary field, realigned to start at a word boundary; bits past the
     # end of the message read as ones, so they never count as terminators
     w0, s = divmod(k_cap * r, WORD_BITS)
-    u_cap = n_words * WORD_BITS - k_cap * r
     uw = u[:, w0:]
     if s:
         nxt = jnp.concatenate(
             [u[:, w0 + 1:], jnp.full((rows, 1), 0xFFFFFFFF, jnp.uint32)],
             axis=1)
         uw = (uw >> s) | (nxt << (WORD_BITS - s))
-    n_u = uw.shape[1]
-    # every 0-bit in the unary region terminates a code; the i-th code's
-    # terminator is the (i+1)-th zero: find its word by a binary search on
-    # the per-word zero counts, then its bit inside that word
+    zpos = _rice_terminators(uw, k_cap, n_words * WORD_BITS - k_cap * r)
+    # code i's quotient is zpos_i - zpos_{i-1} - 1 (zpos_{-1} = -1), so the
+    # quotients of codes 0..i sum to zpos_i - i, and coordinate i (the
+    # gaps (q << r | rem) + 1 summed, less one) is, with int32 wrap-around:
+    i = jnp.arange(k_cap, dtype=jnp.int32)
+    idx = ((zpos - i) << r) + i
+    if r > 0:
+        idx = idx + jnp.cumsum(_rice_remainders(u, k_cap, r), axis=1)
+    return idx.reshape(batch + (k_cap,))
+
+
+def _rice_remainders(u: jax.Array, k_cap: int, r: int) -> jax.Array:
+    """The k_cap r-bit remainder fields at bit 0 of each row of ``u
+    [rows, W]`` -> ``[rows, k_cap]`` int32, read at static offsets: 32
+    codes fill exactly r words, so code j of every 32-code block starts in
+    word (j * r) >> 5 of its block and spills at most into the block's
+    next word. Each j is a strided slice; the 32 of them interleave into
+    code order by one transpose."""
+    rows = u.shape[0]
+    nb = -(-k_cap // WORD_BITS)
+    field = u[:, :nb * r]
+    if field.shape[1] < nb * r:       # the last block's codes past k_cap
+        field = jnp.pad(field, ((0, 0), (0, nb * r - field.shape[1])))
+    mask = jnp.uint32((1 << r) - 1)
+
+    def column(w):                    # word w of every block
+        return jax.lax.slice(field, (0, w), field.shape, (1, r))
+
+    cols = []
+    for j in range(WORD_BITS):
+        w, off = divmod(j * r, WORD_BITS)
+        v = column(w) >> off
+        if off + r > WORD_BITS:
+            v = v | (column(w + 1) << (WORD_BITS - off))
+        cols.append(v & mask)
+    rem = jnp.stack(cols, axis=1).transpose(0, 2, 1)   # [rows, nb, 32]
+    return rem.reshape(rows, nb * WORD_BITS)[:, :k_cap].astype(jnp.int32)
+
+
+def _rice_terminators(uw: jax.Array, k_cap: int, u_cap: int) -> jax.Array:
+    """Bit position in the unary region ``uw [rows, n_u]`` of each code's
+    terminator, the (i+1)-th zero bit -> ``[rows, k_cap]`` int32, or
+    ``u_cap`` where the row holds fewer zeros.
+
+    A rank histogram and prefix scans, not a search. Word w, with ex[w]
+    zeros before it, holds terminators ex[w] + 1 .. ex[w] + zeros[w].
+    Every word is added to slot min(ex[w], k_cap) of a per-row table of
+    k_cap + 1 slots (the last drops the words past code k_cap); ex never
+    falls along a row, so the flat slot indices are sorted. For slot s,
+    the last word W with ex[W] <= s holds terminator s + 1 (if the row has
+    that many zeros), and prefix scans over the slots give W (the words
+    counted, less one), its bits (each word added as its difference from
+    the word before it) and ex[W] (the last slot any word landed in).
+    """
+    rows = uw.shape[0]
     zeros = WORD_BITS - jax.lax.population_count(uw).astype(jnp.int32)
-    cz = jnp.cumsum(zeros, axis=1)
-    tgt = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
-    wi = _first_at_least(cz.reshape(-1), row * n_u, n_u, tgt)
-    at = row * n_u + jnp.minimum(wi, n_u - 1)
-    nth = tgt - (jnp.take(cz.reshape(-1), at) - jnp.take(zeros.reshape(-1),
-                                                          at))
-    bit = _nth_set_bit(~jnp.take(uw.reshape(-1), at), nth)
-    zpos = jnp.where(wi < n_u, wi * WORD_BITS + bit, u_cap)
-    prev = jnp.concatenate(
-        [jnp.full((rows, 1), -1, jnp.int32), zpos[:, :-1]], axis=1)
-    q = zpos - prev - 1
-    gaps = ((q << r) | rem) + 1
-    return (jnp.cumsum(gaps, axis=1) - 1).reshape(batch + (k_cap,))
+    ex = jnp.cumsum(zeros, axis=1) - zeros
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    slot = (row * (k_cap + 1) + jnp.minimum(ex, k_cap)).reshape(-1)
 
+    def ranked(x):
+        t = jnp.zeros((rows * (k_cap + 1),), x.dtype).at[slot].add(
+            x.reshape(-1), indices_are_sorted=True,
+            mode="promise_in_bounds")
+        return t.reshape(rows, k_cap + 1)[:, :k_cap]
 
-def _first_at_least(flat: jax.Array, base: jax.Array, n: int,
-                    tgt: jax.Array) -> jax.Array:
-    """Per row ``b`` (segment ``flat[base_b : base_b + n]``, ascending) and
-    target ``t``: the first index j with ``segment[j] >= t``, or ``n``.
-    A fixed-trip binary search of one-dimensional gathers."""
-    lo = jnp.zeros(jnp.broadcast_shapes(base.shape, tgt.shape), jnp.int32)
-    hi = jnp.full(lo.shape, n, jnp.int32)
-    for _ in range(max(n, 1).bit_length()):
-        mid = (lo + hi) >> 1
-        v = jnp.take(flat, base + jnp.minimum(mid, n - 1))
-        right = (lo < hi) & (v < tgt)
-        lo, hi = (jnp.where(right, mid + 1, lo),
-                  jnp.where((lo < hi) & ~right, mid, hi))
-    return lo
+    prev = jnp.concatenate([jnp.zeros((rows, 1), jnp.uint32), uw[:, :-1]],
+                           axis=1)
+    hist = ranked(jnp.ones_like(zeros))
+    wi = jnp.cumsum(hist, axis=1) - 1
+    word = jnp.cumsum(ranked(uw - prev), axis=1)
+    s = jnp.arange(k_cap, dtype=jnp.int32)
+    ex_w = jax.lax.cummax(jnp.where(hist > 0, s, -1), axis=1)
+    bit = _nth_set_bit(~word, s + 1 - ex_w)
+    return jnp.where(s < jnp.sum(zeros, axis=1, keepdims=True),
+                     wi * WORD_BITS + bit, u_cap)
 
 
 def _nth_set_bit(x: jax.Array, n: jax.Array) -> jax.Array:

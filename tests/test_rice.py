@@ -227,23 +227,81 @@ class TestWordLevelCodec:
             np.testing.assert_array_equal(np.asarray(words), ref_words)
             assert int(used) == ref_used
 
-    @pytest.mark.parametrize("k_cap,r,n_words", [(64, 0, 9), (100, 3, 31),
-                                                 (37, 5, 12), (8, 30, 9)])
-    def test_decode_matches_bit_level_on_any_words(self, k_cap, r, n_words):
+    @pytest.mark.parametrize("k_cap,r,n_words,batch,fill", [
+        pytest.param(64, 0, 9, (3, 2), "random", id="64-0-9"),
+        pytest.param(100, 3, 31, (3, 2), "random", id="100-3-31"),
+        pytest.param(37, 5, 12, (3, 2), "random", id="37-5-12"),
+        pytest.param(8, 30, 9, (3, 2), "random", id="8-30-9"),
+        # k_cap off a multiple of 32, 7-bit fields straddling words
+        pytest.param(45, 7, 20, (3, 2), "random", id="45-7-20"),
+        # one bit in eight set: far more zeros than codes
+        pytest.param(40, 2, 64, (3, 2), "sparse", id="40-2-64-sparse"),
+        # the zeroed-header skip sentinel
+        pytest.param(70, 3, 16, (3, 2), "zero", id="70-3-16-zero"),
+        pytest.param(96, 4, 40, (4, 3), "random", id="96-4-40-batch4x3"),
+        # encoder output ending inside its last word, zero padding after
+        pytest.param(50, 0, 12, (3, 2), "encoded", id="50-0-12-ragged"),
+    ])
+    def test_decode_matches_bit_level_on_any_words(self, k_cap, r, n_words,
+                                                   batch, fill):
         """Random words (valid or not, too few zeros included) decode as
         the bit-level reference does, batched and unbatched."""
         rng = np.random.default_rng(k_cap * 31 + r)
-        words = rng.integers(-2**31, 2**31, (3, 2, n_words), dtype=np.int64)
-        words[0, 0] = -1                         # no zero bit at all
-        words[0, 1, :n_words // 2] = 0           # a run of zero words
-        words = words.astype(np.int32)
+        shape = batch + (n_words,)
+        words = rng.integers(-2**31, 2**31, shape, dtype=np.int64)
+        if fill == "random":
+            words[0, 0] = -1                         # no zero bit at all
+            words[0, 1, :n_words // 2] = 0           # a run of zero words
+        elif fill == "sparse":
+            bits = rng.random(shape + (32,)) < 0.125
+            words = (bits << np.arange(32)).sum(-1)
+        elif fill == "zero":
+            words[:] = 0
+        elif fill == "encoded":
+            for b in np.ndindex(batch):
+                x = rng.integers(0, 8, k_cap)
+                w, used = _bit_pack_gaps(x, r, n_words)
+                assert 0 < (k_cap * r + x.sum() + k_cap) % 32   # ragged
+                words[b] = w
+                assert not words[b][used:].any()
+        words = words.astype(np.uint32).view(np.int32)
         got = np.asarray(self.decode(jnp.asarray(words), k_cap, 0, r))
         for b in np.ndindex(words.shape[:-1]):
             np.testing.assert_array_equal(got[b],
                                           _bit_decode(words[b], k_cap, r),
                                           err_msg=str(b))
+        if fill == "zero":
+            np.testing.assert_array_equal(
+                got, np.broadcast_to(np.arange(k_cap), got.shape))
         one = np.asarray(self.decode(jnp.asarray(words[1, 1]), k_cap, 0, r))
         np.testing.assert_array_equal(one, got[1, 1])
+
+    @pytest.mark.parametrize("r", [0, 3])
+    def test_decode_cost_does_not_grow_with_the_stream(self, r):
+        """No search over the unary region: the decoder traces to the
+        same number of gathers (at most one) at 64 and at 65536 unary
+        words, and to no loop."""
+        k_cap = 4096
+
+        def primitives(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else (v,):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from primitives(sub)
+
+        def count(n_words):
+            spec = jax.ShapeDtypeStruct((2, n_words), jnp.int32)
+            jaxpr = jax.make_jaxpr(
+                lambda w: compaction.rice_decode(w, k_cap, 0, r))(spec)
+            names = list(primitives(jaxpr.jaxpr))
+            assert "while" not in names
+            return names.count("gather")
+
+        small = count(k_cap * r // 32 + 64)
+        assert small == count(65536) and small <= 1
 
 
 class TestRealizedEqualsModel:
