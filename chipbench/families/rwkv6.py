@@ -8,13 +8,13 @@ import jax.numpy as jnp
 
 from chipbench.reference import layernorm
 
-PREFIX = "blocks/b0_rwkv/"
 MATMUL = ("tm/wr", "tm/wk", "tm/wv", "tm/wg", "tm/wo", "tm/lora_a",
           "tm/lora_b", "tm/w_lora_a", "tm/w_lora_b", "cm/wk", "cm/wv",
           "cm/wr")
+PERIOD = ("rwkv",)
 
 
-def block(conf: dict) -> dict:
+def block(conf: dict, kind: str) -> dict:
     d, hd, f = (conf["hidden_size"], conf["head_size"],
                 conf["intermediate_size"])
     r1, r2 = conf["time_mix_extra_dim"], conf["time_decay_extra_dim"]
@@ -77,7 +77,7 @@ def _wkv(r, k, v, w, u, chunk=64):
     return out.reshape(s, h, dk)
 
 
-def layer(conf, mm, p, x):
+def layer(conf, mm, kind, p, x):
     d, hd = conf["hidden_size"], conf["head_size"]
     nh, eps = d // hd, conf["layer_norm_epsilon"]
     s = x.shape[0]

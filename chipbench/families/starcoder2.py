@@ -4,16 +4,15 @@ from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 
-from chipbench.reference import layernorm
+from chipbench.reference import causal_attention, layernorm
 
-PREFIX = "blocks/b0_attn_sw/"
 MATMUL = ("attn/wq", "attn/wk", "attn/wv", "attn/wo", "ffn/up", "ffn/down")
+PERIOD = ("attn_sw",)
 
 
-def block(conf: dict) -> dict:
+def block(conf: dict, kind: str) -> dict:
     d, f = conf["hidden_size"], conf["intermediate_size"]
     h, kv = conf["num_attention_heads"], conf["num_key_value_heads"]
     hd = conf["head_dim"]
@@ -73,33 +72,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(mm, q, k, v, window, block=512):
-    """Causal sliding-window attention of one row, in blocks of queries.
-    q [S, H, D] (already scaled), k/v [S, KV, D]; head h reads kv head
-    h // (H // KV)."""
-    s, h, _ = q.shape
-    groups = h // k.shape[1]
-    kk = jnp.repeat(k, groups, axis=1)
-    vv = jnp.repeat(v, groups, axis=1)
-    blk = min(block, s)
-    kpos = jnp.arange(s)
-
-    @jax.checkpoint
-    def one(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * blk, blk, 0)
-        sc = mm("qhd,khd->hqk", qb, kk)
-        qpos = i * blk + jnp.arange(blk)
-        ok = (kpos[None, :] <= qpos[:, None]) & (
-            qpos[:, None] - kpos[None, :] < window)
-        sc = jnp.where(ok[None], sc, -1e30)
-        pr = jax.nn.softmax(sc, axis=-1)
-        return mm("hqk,khd->qhd", pr, vv)
-
-    out = jax.lax.map(one, jnp.arange(s // blk))
-    return out.reshape(q.shape)
-
-
-def layer(conf, mm, p, x):
+def layer(conf, mm, kind, p, x):
     hd = conf["head_dim"]
     eps = conf["norm_epsilon"]
     a = layernorm(x, p["ln1/scale"], p["ln1/bias"], eps)
@@ -108,7 +81,7 @@ def layer(conf, mm, p, x):
     v = mm("sd,dhk->shk", a, p["attn/wv"]) + p["attn/bv"]
     q = _rope(q * hd ** -0.5, conf["rope_theta"])
     k = _rope(k, conf["rope_theta"])
-    o = _attention(mm, q, k, v, conf["sliding_window"])
+    o = causal_attention(mm, q, k, v, conf["sliding_window"])
     x = x + mm("shk,hkd->sd", o, p["attn/wo"]) + p["attn/bo"]
     b = layernorm(x, p["ln2/scale"], p["ln2/bias"], eps)
     f = _gelu_tanh(mm("sd,df->sf", b, p["ffn/up"]) + p["ffn/up_b"])

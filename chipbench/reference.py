@@ -6,9 +6,10 @@ program's tree (the data convention the weights are made in), so both
 sides start from the same weights made from the seed. One step:
 
 1. loss and gradient of the language model in float32, matmuls at
-   ``highest`` precision, each layer as the configuration's family file
-   (``families/<family>.py``) gives it, tied unembedding, token-mean cross
-   entropy over all but the last position of each row;
+   ``highest`` precision: the layers and the final norm as the
+   configuration's family file (``families/<family>.py``) gives them, tied
+   unembedding, token-mean cross entropy over all but the last position of
+   each row, plus the family's router loss where its layers route;
 2. error feedback: the target is gradient plus carried residual;
 3. gspar (the paper's Algorithm 3, greedy, two rescales) per row — one row
    per layer of a stacked leaf, else the whole leaf; leaves under
@@ -44,14 +45,52 @@ BETA1, BETA2, ADAM_EPS = 0.9, 0.999, 1e-8
 # layout
 # ---------------------------------------------------------------------------
 
+def blocks(conf: dict) -> list:
+    """``[(prefix, layers, kind)]`` in program order: each prelude block,
+    unstacked (``layers`` None) under ``prelude/p<j>_<kind>/``, then each
+    kind of the period, stacked ``layers`` deep under ``blocks/b<j>_<kind>/``,
+    as many periods as the layers left after the prelude fill."""
+    fam = families.get(conf["family"])
+    prelude = getattr(fam, "prelude", lambda conf: ())(conf)
+    periods, rest = divmod(conf["num_hidden_layers"] - len(prelude),
+                           len(fam.PERIOD))
+    if rest:
+        raise ValueError(f"{conf['family']}: {conf['num_hidden_layers']} "
+                         f"layers are no whole number of periods")
+    out = [(f"prelude/p{j}_{kind}/", None, kind)
+           for j, kind in enumerate(prelude)]
+    out += [(f"blocks/b{j}_{kind}/", periods, kind)
+            for j, kind in enumerate(fam.PERIOD)]
+    return out
+
+
+def final_norm(conf: dict) -> dict:
+    """``{leaf under final_ln/: shape}``: the family's, else a LayerNorm's
+    scale and bias."""
+    fam = families.get(conf["family"])
+    if hasattr(fam, "final_norm"):
+        return fam.final_norm(conf)
+    return {"bias": (conf["hidden_size"],), "scale": (conf["hidden_size"],)}
+
+
 def layout(conf: dict) -> dict:
     """``{leaf path: shape}`` of the model described by ``conf``."""
     fam = families.get(conf["family"])
-    L, d, V = conf["num_hidden_layers"], conf["hidden_size"], conf["vocab_size"]
-    out = {"embed/table": (V, d), "final_ln/bias": (d,),
-           "final_ln/scale": (d,)}
-    out.update({fam.PREFIX + k: (L,) + s for k, s in fam.block(conf).items()})
+    out = {"embed/table": (conf["vocab_size"], conf["hidden_size"])}
+    out.update({"final_ln/" + k: s for k, s in final_norm(conf).items()})
+    for prefix, layers, kind in blocks(conf):
+        lead = () if layers is None else (layers,)
+        out.update({prefix + k: lead + s
+                    for k, s in fam.block(conf, kind).items()})
     return dict(sorted(out.items()))
+
+
+def block_leaf(path: str) -> str | None:
+    """A leaf's path inside its block (``attn/wq``), None outside blocks."""
+    head, _, rest = path.partition("/")
+    if head in ("prelude", "blocks"):
+        return rest.partition("/")[2]
+    return None
 
 
 def rows_of(path: str, shape: tuple) -> int:
@@ -87,19 +126,56 @@ def layernorm(x, scale, bias, eps):
     return (x - mean) / jnp.sqrt(var + eps) * scale + bias
 
 
+def causal_attention(mm, q, k, v, window=None, block=512):
+    """Causal attention of one row, in blocks of queries, over the last
+    ``window`` keys where one is given. q [S, H, Dk] (already scaled),
+    k [S, KV, Dk], v [S, KV, Dv]; head h reads kv head h // (H // KV)."""
+    s, h, _ = q.shape
+    groups = h // k.shape[1]
+    kk = jnp.repeat(k, groups, axis=1)
+    vv = jnp.repeat(v, groups, axis=1)
+    blk = min(block, s)
+    kpos = jnp.arange(s)
+
+    @jax.checkpoint
+    def one(i):
+        qb = jax.lax.dynamic_slice_in_dim(q, i * blk, blk, 0)
+        sc = mm("qhd,khd->hqk", qb, kk)
+        qpos = i * blk + jnp.arange(blk)
+        ok = kpos[None, :] <= qpos[:, None]
+        if window is not None:
+            ok = ok & (qpos[:, None] - kpos[None, :] < window)
+        sc = jnp.where(ok[None], sc, -1e30)
+        pr = jax.nn.softmax(sc, axis=-1)
+        return mm("hqk,khd->qhd", pr, vv)
+
+    out = jax.lax.map(one, jnp.arange(s // blk))
+    return out.reshape(s, h, v.shape[-1])
+
+
 def _row_nll(conf, mm, params, tokens, block=1024):
-    """Summed next-token NLL of one row over positions ``[0, S-1)``, and
-    their count."""
+    """Summed next-token NLL of one row over positions ``[0, S-1)``, their
+    count, and the router statistics of each layer that returns them."""
     fam = families.get(conf["family"])
-    prefix = fam.PREFIX
     table = params["embed/table"]
     x = table[tokens]
-    for i in range(conf["num_hidden_layers"]):
-        p = {k[len(prefix):]: v[i] for k, v in params.items()
-             if k.startswith(prefix)}
-        x = jax.checkpoint(functools.partial(fam.layer, conf, mm))(p, x)
-    x = layernorm(x, params["final_ln/scale"], params["final_ln/bias"],
-                  fam.final_norm_eps(conf))
+    stats = []
+    for prefix, layers, kind in blocks(conf):
+        own = {k[len(prefix):]: v for k, v in params.items()
+               if k.startswith(prefix)}
+        f = jax.checkpoint(functools.partial(fam.layer, conf, mm, kind))
+        for i in range(layers or 1):
+            p = own if layers is None else {k: v[i] for k, v in own.items()}
+            out = f(p, x)
+            x, st = out if isinstance(out, tuple) else (out, None)
+            if st is not None:
+                stats.append(st)
+    fin = {k[len("final_ln/"):]: v for k, v in params.items()
+           if k.startswith("final_ln/")}
+    if hasattr(fam, "final_norm_apply"):
+        x = fam.final_norm_apply(conf, fin, x)
+    else:
+        x = layernorm(x, fin["scale"], fin["bias"], fam.final_norm_eps(conf))
     s = x.shape[0]
     targets = jnp.concatenate([tokens[1:], tokens[:1]])
     valid = (jnp.arange(s) < s - 1).astype(jnp.float32)
@@ -115,16 +191,23 @@ def _row_nll(conf, mm, params, tokens, block=1024):
         gold = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
         return jnp.sum((lse - gold) * vb)
 
-    return jnp.sum(jax.lax.map(one, jnp.arange(s // blk))), jnp.sum(valid)
+    nll = jnp.sum(jax.lax.map(one, jnp.arange(s // blk)))
+    return nll, jnp.sum(valid), stats
 
 
 def loss_fn(conf: dict, params: dict, tokens, lowp=False, fault=None):
-    """Token-mean next-token cross entropy over the batch [B, S]."""
+    """Token-mean next-token cross entropy over the batch [B, S], plus the
+    family's router loss of the statistics its layers return, each with a
+    leading batch axis (the program adds its router loss so)."""
     mm = _op(lowp)
     if fault == "half_batch":
         tokens = tokens[:, :tokens.shape[1] // 2]
-    nll, n = jax.vmap(lambda t: _row_nll(conf, mm, params, t))(tokens)
-    return jnp.sum(nll) / jnp.sum(n)
+    nll, n, stats = jax.vmap(
+        lambda t: _row_nll(conf, mm, params, t))(tokens)
+    loss = jnp.sum(nll) / jnp.sum(n)
+    if stats:
+        loss = loss + families.get(conf["family"]).router_loss(conf, stats)
+    return loss
 
 
 # ---------------------------------------------------------------------------

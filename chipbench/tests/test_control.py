@@ -12,7 +12,8 @@ from chipbench import compare, data, reference
 import tiny
 
 
-@pytest.fixture(scope="module", params=["tiny-sc2", "tiny-rwkv6"])
+@pytest.fixture(scope="module", params=["tiny-sc2", "tiny-rwkv6",
+                                                 "tiny-dsv2"])
 def readings(request):
     cell = tiny.cell(request.param)
     conf, traffic = cell.config, cell.traffic

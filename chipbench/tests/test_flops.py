@@ -31,6 +31,26 @@ def test_rwkv6_by_hand():
         == 6 * 466878464 + 12 * 6 * d * 64 == 2810707968
 
 
+def test_deepseek2_by_hand():
+    import tiny
+    c = json.loads((tiny.DATA / "tiny-dsv2.json").read_text())
+    # MLA: q_down 128 x 48, q_up 48 x 4 x 24, kv_down 128 x 32, k_rope
+    # 128 x 8, k_up and v_up 32 x 4 x 16, wo 4 x 16 x 128
+    mla = 128 * 48 + 48 * 4 * 24 + 128 * 32 + 128 * 8 + 2 * 32 * 4 * 16 \
+        + 4 * 16 * 128
+    assert mla == 28160
+    dense = 3 * 128 * 256                      # gate, up, down
+    # router 128 x 4, one shared expert of width 64, 4 routed experts of
+    # width 64 of which a token uses top_k 2 of the published 4
+    moe = 128 * 4 + 3 * 128 * 64 + 4 * 3 * 128 * 64 * 2 // 4
+    assert flops.matmul_params(c) == 512 * 128 + 3 * mla + dense + 2 * moe \
+        == 396800
+    # causal MLA over 64 positions: (64 + 1) / 2 keys, Q.K over 16 + 8,
+    # P.V over 16, 4 heads, 3 layers
+    attn = 6 * 3 * 4 * (16 + 8 + 16) * 32.5
+    assert flops.per_token(c, 64) == 6 * 396800 + attn == 2474400
+
+
 def test_mean_context():
     from chipbench.families import starcoder2
     assert starcoder2.mean_context(4, None) == 2.5

@@ -13,7 +13,7 @@ BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 CASES = [(w["name"], spec.load(w["name"], BENCH).cell)
          for w in BENCH["workloads"]]
 CASES += [(n, json.loads((tiny.DATA / f"{n}.limits.json").read_text()))
-          for n in ("tiny-sc2", "tiny-rwkv6")]
+          for n in ("tiny-sc2", "tiny-rwkv6", "tiny-dsv2")]
 
 
 @pytest.mark.parametrize("name,entry", CASES, ids=[c[0] for c in CASES])
