@@ -20,7 +20,7 @@ SHAPES: dict[str, tuple[int, int, str]] = {
 ARCHS = [
     "gemma2_9b", "gemma_2b", "paligemma_3b", "seamless_m4t_large_v2",
     "starcoder2_7b", "phi35_moe", "deepseek_v2", "rwkv6_1b6",
-    "zamba2_2b7", "gemma2_27b",
+    "zamba2_2b7", "gemma2_27b", "deepseek_v2_lite",
 ]
 
 # canonical ids as assigned (hyphens) -> module names
@@ -32,6 +32,7 @@ ID_TO_MODULE = {
     "starcoder2-7b": "starcoder2_7b",
     "phi3.5-moe-42b-a6.6b": "phi35_moe",
     "deepseek-v2-236b": "deepseek_v2",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "rwkv6-1.6b": "rwkv6_1b6",
     "zamba2-2.7b": "zamba2_2b7",
     "gemma2-27b": "gemma2_27b",

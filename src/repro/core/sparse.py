@@ -164,9 +164,9 @@ def _residual_from_buffers(g: jax.Array, sg: SparseGrad) -> jax.Array:
     pair: a single scatter-subtract into the target. Padding slots carry
     exact zeros, so they are no-ops; elementwise it equals
     ``g - sg.densify()`` bit-for-bit — and hence the dense-wire residual
-    ``target - Q(target)`` whenever nothing overflows the capacity (which
-    the k_cap sizing guarantees; on overflow this form re-carries the
-    dropped survivors' error rather than losing it). The subtracted values
+    ``target - Q(target)`` whenever nothing overflows the capacity (on
+    overflow this form re-carries the dropped survivors' error rather than
+    losing it, as the Pallas emit does). The subtracted values
     are codec-*decoded* — what the wire actually delivers — so quantization
     error of kept values (bf16 rounding, qsgd/ternary levels) is absorbed
     into the residual instead of silently dropped.

@@ -17,13 +17,23 @@ metadata: the compiled program is the same with or without it.
   decode     value decode and wire layout decode of the gathered buffers
   apply      scatter-add of the gathered buffers into the averaged gradient
   optimizer  the optimizer update
+
+Inside ``model``, a mixture-of-experts layer (``repro.models.moe``) names
+its own (``MOE_STAGES``):
+
+  route      router logits, top-k, the balance term
+  dispatch   ordering choices by expert and gathering them into the held
+             experts' slot rows
+  experts    the held and shared experts' matmuls
+  combine    gathering the slot outputs back, weighting, summing
 """
 from __future__ import annotations
 
 import jax
 
+MOE_STAGES = ("route", "dispatch", "experts", "combine")
 STAGES = ("model", "compress", "compact", "pack", "exchange", "decode",
-          "apply", "optimizer")
+          "apply", "optimizer") + MOE_STAGES
 
 
 def stage(name: str):

@@ -424,7 +424,8 @@ DROP_SLOT = 2**31 - 1
 
 
 def _compact_emit_body(g_ref, u_ref, s1_ref, s2_ref, up_ref, lo_ref, off_ref,
-                       slot_ref, val_ref, *res_ref, pkind: str, wire_dtype):
+                       slot_ref, val_ref, *res_ref, pkind: str, wire_dtype,
+                       k_cap: int):
     i = pl.program_id(0)
     g = g_ref[...].astype(jnp.float32)
     _, z, v, _ = _tile_select(pkind, g, jnp.abs(g), u_ref[...], s1_ref[0, 0],
@@ -435,16 +436,16 @@ def _compact_emit_body(g_ref, u_ref, s1_ref, s2_ref, up_ref, lo_ref, off_ref,
     slot_ref[...] = jnp.where(z, rank, DROP_SLOT)
     val_ref[...] = v
     if res_ref:
-        # subtract what the wire carries (v rounded to the wire dtype), for
-        # ALL survivors — overflow-dropped ones were sampled, just not
-        # transmitted (documented fused-EF semantics)
-        sent = v.astype(wire_dtype).astype(jnp.float32)
+        # subtract what the wire carries (v rounded to the wire dtype); a
+        # survivor ranked past k_cap is not sent and stays whole
+        sent = jnp.where(z & (rank >= k_cap), 0.0,
+                         v.astype(wire_dtype).astype(jnp.float32))
         res_ref[0][...] = (g - sent).astype(res_ref[0].dtype)
 
 
 def compact_emit_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
                     offsets: jax.Array, up: jax.Array, lo: jax.Array, *,
-                    pkind: str, wire_dtype=None, ef: bool = False,
+                    pkind: str, k_cap: int, wire_dtype=None, ef: bool = False,
                     interpret: bool = False):
     """Pass 2 of the two-pass compaction: re-derive the kept mask per tile
     and give every survivor its global compact rank.
@@ -454,9 +455,10 @@ def compact_emit_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
     the tiles are independent. Emits ``(slot [r, c] int32, v [r, c] f32,
     residual)``: ``slot`` is the compact rank of each survivor (DROP_SLOT
     elsewhere) and ``v`` its amplified value; the compact ``(values, idx)``
-    buffers are one XLA scatter of these. ``ef=True`` additionally emits
-    ``residual [r, c]`` = g minus the survivors' values rounded to
-    ``wire_dtype`` (None when not requested)."""
+    buffers are one XLA scatter of these, which drops ranks past
+    ``k_cap``. ``ef=True`` additionally emits ``residual [r, c]`` = g minus
+    what is sent: the values of the survivors ranked under ``k_cap``,
+    rounded to ``wire_dtype`` (None when not requested)."""
     r, c = g.shape
     assert c == BLOCK_C, "two-pass kernels require the _pad_2d layout"
     grid = (r // BLOCK_R,)
@@ -471,7 +473,7 @@ def compact_emit_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
         out_shape.append(jax.ShapeDtypeStruct((r, c), g.dtype))
     out = pl.pallas_call(
         functools.partial(_compact_emit_body, pkind=pkind,
-                          wire_dtype=wire_dtype or g.dtype),
+                          wire_dtype=wire_dtype or g.dtype, k_cap=k_cap),
         grid=grid,
         in_specs=[tile, tile, _smem(), _smem(),
                   pl.BlockSpec((BLOCK_C, BLOCK_C), lambda i: (0, 0)),

@@ -182,8 +182,9 @@ def _two_pass(flat: jax.Array, u: jax.Array | None, s1, s2, *, pkind: str,
     wire_dtype = codec.wire_dtype(flat.dtype)
     with stage("compact"):
         slot, v, res = K.compact_emit_2d(g2d, u2d, s1, s2, offsets, up, lo,
-                                         pkind=pkind, wire_dtype=wire_dtype,
-                                         ef=ef, interpret=interpret)
+                                         pkind=pkind, k_cap=k_cap,
+                                         wire_dtype=wire_dtype, ef=ef,
+                                         interpret=interpret)
         # scatter from the kernels' 2-D layout: flattening slot first costs
         # the TPU compiler a minute per vmapped group and buys nothing
         vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
@@ -338,12 +339,10 @@ def gspar_sparse_ef(g: jax.Array, u: jax.Array, k_cap: int, rho: float = 0.1,
     g's dtype and values in ``out_dtype`` (the codec's wire dtype; the
     in-pass subtraction therefore charges the wire rounding of kept values
     to the residual with no post-hoc fold). On overflow (nnz > k_cap) the
-    dropped survivors remain *subtracted* from the residual — they were
-    sampled, just not transmitted — matching the dense-wire semantics of
-    ``target - Q(target)``; the reference sparse backend instead
-    re-carries their error (residual = target - transmitted). The two
-    agree exactly at zero overflow, which the ``capacity_for`` sizing
-    guarantees in configured operation."""
+    survivors past the capacity are sampled but not transmitted, and the
+    residual carries them whole: ``residual = g - transmitted``, the
+    reference sparse backend's rule. ``capacity_for`` sizes k_cap at 1.25x
+    the mean draw, which a row of a few thousand coordinates can exceed."""
     codec = _codec_for(out_dtype)
     er, lam = gspar_emit(g, u, None, k_cap=k_cap, rho=rho,
                          num_iters=num_iters, codec=codec, ef=True,

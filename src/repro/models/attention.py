@@ -1,6 +1,7 @@
 """Attention variants: GQA/MQA with RoPE, sliding windows, logit softcap,
-cross-attention, and DeepSeek-V2 MLA (latent KV compression) with the
-absorbed-projection decode path. All functions are pure; caches are dicts of
+cross-attention, and DeepSeek-V2 MLA (latent KV compression; queries with
+or without their own latent, optional RMSNorms on the latents, plain RoPE
+or YaRN) with the absorbed-projection decode path. All functions are pure; caches are dicts of
 arrays handled functionally.
 
 Cache layouts (per layer):
@@ -19,7 +20,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import logical_constraint
 from repro.models.common import Initializer
-from repro.models.layers import apply_rope, rope_table, softcap
+from repro.models.layers import (apply_rope, rmsnorm, rope_table, softcap,
+                                 yarn_softmax_scale)
 
 NEG_INF = -2.0e38
 
@@ -381,24 +383,31 @@ class MLAConfig:
     d_model: int
     num_heads: int
     kv_lora: int = 512
-    q_lora: int = 1536
+    q_lora: int = 1536                 # 0: queries straight from wq
     qk_nope: int = 128
     qk_rope: int = 64
     v_dim: int = 128
     rope_theta: float = 10000.0
     logit_softcap: float | None = None
+    latent_norm: bool = False          # RMSNorm on each latent
+    rope_scaling: tuple | None = None  # YaRN, as (key, value) pairs
 
     @property
     def scale(self) -> float:
-        return (self.qk_nope + self.qk_rope) ** -0.5
+        return ((self.qk_nope + self.qk_rope) ** -0.5
+                * yarn_softmax_scale(self.rope_scaling))
 
 
 def init_mla(ini: Initializer, cfg: MLAConfig):
     d, h = cfg.d_model, cfg.num_heads
-    return {
-        "q_down": ini.fan_in((d, cfg.q_lora), ("embed", "kv_lora")),
-        "q_up": ini.fan_in((cfg.q_lora, h, cfg.qk_nope + cfg.qk_rope),
-                           ("kv_lora", "heads", "head_dim")),
+    qk = cfg.qk_nope + cfg.qk_rope
+    if cfg.q_lora:
+        p = {"q_down": ini.fan_in((d, cfg.q_lora), ("embed", "kv_lora")),
+             "q_up": ini.fan_in((cfg.q_lora, h, qk),
+                                ("kv_lora", "heads", "head_dim"))}
+    else:
+        p = {"wq": ini.fan_in((d, h, qk), ("embed", "heads", "head_dim"))}
+    p.update({
         "kv_down": ini.fan_in((d, cfg.kv_lora), ("embed", "kv_lora")),
         "k_rope": ini.fan_in((d, cfg.qk_rope), ("embed", "qk_rope")),
         "k_up": ini.fan_in((cfg.kv_lora, h, cfg.qk_nope),
@@ -407,16 +416,30 @@ def init_mla(ini: Initializer, cfg: MLAConfig):
                            ("kv_lora", "heads", "head_dim")),
         "wo": ini.fan_in((h, cfg.v_dim, d), ("heads", "head_dim", "embed"),
                          in_dim_idx=1),
-    }
+    })
+    if cfg.latent_norm:
+        p["kv_norm"] = {"scale": ini.zeros((cfg.kv_lora,), ("kv_lora",))}
+        if cfg.q_lora:
+            p["q_norm"] = {"scale": ini.zeros((cfg.q_lora,), ("kv_lora",))}
+    return p
 
 
 def _mla_qc(p, cfg: MLAConfig, x, positions):
     """Queries + latent (c_kv, k_rope) for a block of tokens."""
-    q = jnp.einsum("bsd,dl,lhk->bshk", x, p["q_down"], p["q_up"])
+    if cfg.q_lora:
+        c_q = jnp.einsum("bsd,dl->bsl", x, p["q_down"])
+        if cfg.latent_norm:
+            c_q = rmsnorm(p["q_norm"], c_q)
+        q = jnp.einsum("bsl,lhk->bshk", c_q, p["q_up"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :cfg.qk_nope], q[..., cfg.qk_nope:]
     c_kv = jnp.einsum("bsd,dl->bsl", x, p["kv_down"])
+    if cfg.latent_norm:
+        c_kv = rmsnorm(p["kv_norm"], c_kv)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["k_rope"])
-    sin, cos = rope_table(positions, cfg.qk_rope, cfg.rope_theta)
+    sin, cos = rope_table(positions, cfg.qk_rope, cfg.rope_theta,
+                          cfg.rope_scaling)
     q_rope = apply_rope(q_rope, sin, cos)
     k_rope = apply_rope(k_rope[:, :, None, :], sin, cos)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope

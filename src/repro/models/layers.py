@@ -1,6 +1,8 @@
 """Shared building blocks: norms, rotary embeddings, MLPs, embedding table."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -44,12 +46,62 @@ NORMS = {"rms": (init_rmsnorm, rmsnorm), "layer": (init_layernorm, layernorm)}
 # Rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rope_table(positions: jax.Array, head_dim: int, theta: float = 10000.0):
-    """positions [*, S] -> (sin, cos) of shape [*, S, head_dim/2], fp32."""
+def yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_softmax_scale(scaling) -> float:
+    """Factor on the attention softmax scale under YaRN (``scaling`` as
+    ``(key, value)`` pairs of a published ``rope_scaling``): the square of
+    ``mscale(mscale_all_dim)``; 1 without scaling."""
+    if scaling is None:
+        return 1.0
+    y = dict(scaling)
+    m_all = y.get("mscale_all_dim", 0)
+    return yarn_mscale(y["factor"], m_all) ** 2 if m_all else 1.0
+
+
+def _yarn(scaling, freq, head_dim: int, theta: float):
+    """YaRN (arXiv:2309.00071) as DeepSeek-V2 publishes it: frequencies
+    blended between the plain and the ``factor``-interpolated ones over the
+    ramp between ``beta_fast`` and ``beta_slow`` rotations in the original
+    context, and the cos/sin scale ``mscale(mscale) /
+    mscale(mscale_all_dim)``."""
+    y = dict(scaling)
+    if y.get("type", y.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {y!r}: only YaRN is implemented")
+    factor, orig = y["factor"], y["original_max_position_embeddings"]
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(y["beta_fast"])), 0)
+    high = min(math.ceil(corr_dim(y["beta_slow"])), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = 1.0 - ramp                 # where the plain frequency is kept
+    freq = freq / factor * (1.0 - extra) + freq * extra
+    mscale = (yarn_mscale(factor, y.get("mscale", 1))
+              / yarn_mscale(factor, y.get("mscale_all_dim", 0)))
+    return freq, mscale
+
+
+def rope_table(positions: jax.Array, head_dim: int, theta: float = 10000.0,
+               scaling=None):
+    """positions [*, S] -> (sin, cos) of shape [*, S, head_dim/2], fp32;
+    YaRN frequencies and cos/sin scale under ``scaling`` (pairs)."""
     half = head_dim // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    mscale = 1.0
+    if scaling is not None:
+        freq, mscale = _yarn(scaling, freq, head_dim, theta)
     angles = positions.astype(jnp.float32)[..., None] * freq
-    return jnp.sin(angles), jnp.cos(angles)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
+    return sin, cos
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

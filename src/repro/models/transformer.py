@@ -44,6 +44,7 @@ class ModelConfig:
     num_kv_heads: int = 8
     head_dim: int = 64
     rope_theta: float = 10000.0
+    rope_scaling: tuple | None = None   # MLA YaRN, as (key, value) pairs
     window: int | None = None           # for attn_sw blocks
     attn_softcap: float | None = None
     query_scale: float | None = None
@@ -68,6 +69,7 @@ class ModelConfig:
     mla_qk_nope: int = 128
     mla_qk_rope: int = 64
     mla_v: int = 128
+    mla_latent_norm: bool = False       # RMSNorm on the MLA latents
     rwkv: ssm_lib.RWKV6Config | None = None
     mamba: ssm_lib.Mamba2Config | None = None
     shared_lora_rank: int = 64          # zamba2 per-use adapters
@@ -103,7 +105,9 @@ class ModelConfig:
         return attn.MLAConfig(d_model=self.d_model, num_heads=self.num_heads,
                               kv_lora=self.mla_kv_lora, q_lora=self.mla_q_lora,
                               qk_nope=self.mla_qk_nope, qk_rope=self.mla_qk_rope,
-                              v_dim=self.mla_v, rope_theta=self.rope_theta)
+                              v_dim=self.mla_v, rope_theta=self.rope_theta,
+                              latent_norm=self.mla_latent_norm,
+                              rope_scaling=self.rope_scaling)
 
 
 def _norm(cfg, p, x):
@@ -133,13 +137,27 @@ def _init_ffn(ini, cfg: ModelConfig, dense_ff: int | None = None):
     return init_dense_mlp(ini, cfg.d_model, cfg.d_ff)
 
 
+def _aux(cfg: ModelConfig, loss=None, fill=None) -> dict:
+    """A block's auxiliary outputs: its router ``loss`` and, in a model
+    with MoE layers, ``moe_layers`` (1 in a MoE block) and the block's
+    ``fill`` (``moe.moe_layer``'s slot fill), so that they add up over the
+    stack with one structure in every block."""
+    zero = jnp.zeros((), jnp.float32)
+    out = {"loss": zero if loss is None else loss}
+    if cfg.moe is not None:
+        out["moe_layers"] = zero if fill is None else jnp.ones((), jnp.float32)
+        out["fill"] = zero if fill is None else fill
+    return out
+
+
 def _ffn(fp, cfg: ModelConfig, x, dense: bool = False):
     kind = _ffn_kind(cfg, dense)
     if kind == "moe":
-        return moe_lib.moe_ffn(fp, cfg.moe, x)
+        y, loss, fill = moe_lib.moe_layer(fp, cfg.moe, x)
+        return y, _aux(cfg, loss, fill)
     if kind == "gated":
-        return gated_mlp(fp, x, cfg.act), jnp.zeros((), jnp.float32)
-    return dense_mlp(fp, x, cfg.act), jnp.zeros((), jnp.float32)
+        return gated_mlp(fp, x, cfg.act), _aux(cfg)
+    return dense_mlp(fp, x, cfg.act), _aux(cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -196,8 +214,8 @@ def _residual(cfg, p, x, delta, post_key):
 
 def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str,
                 cache=None, pos=None, shared=None, emb0=None, causal=True):
-    """Apply one block. Returns (x, new_cache, aux_loss)."""
-    zero = jnp.zeros((), jnp.float32)
+    """Apply one block. Returns (x, new_cache, aux) with aux as ``_aux``."""
+    no_aux = _aux(cfg)
     if kind in ("attn_full", "attn_sw", "mla", "mla_dense"):
         is_mla = kind.startswith("mla")
         acfg = cfg.mla_cfg() if is_mla else cfg.attn_cfg(kind)
@@ -233,13 +251,13 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str,
         c, cm = ssm_lib.rwkv6_channel_mix(p["cm"], _norm(cfg, p["ln2"], x), st)
         x = x + c
         nc = None if mode == "train" else {**tm, **cm}
-        return x, nc, zero
+        return x, nc, no_aux
 
     if kind == "mamba":
         h = _norm(cfg, p["ln1"], x)
         a, st = ssm_lib.mamba2_mix(p["mix"], cfg.mamba, h,
                                    None if mode == "train" else cache)
-        return x + a, (None if mode == "train" else st), zero
+        return x + a, (None if mode == "train" else st), no_aux
 
     if kind == "shared_attn":
         cat = jnp.concatenate([x, emb0.astype(x.dtype)], axis=-1)
@@ -256,7 +274,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str,
         h = h + a
         f, _ = _ffn(shared["ffn"], cfg, _norm(cfg, shared["ln2"], h), dense=True)
         h = h + f
-        return x + h @ shared["out_proj"], nc, zero
+        return x + h @ shared["out_proj"], nc, no_aux
 
     raise ValueError(f"unknown block kind {kind!r}")
 
@@ -381,10 +399,15 @@ def _cross_apply(xp, cfg, x, mode, enc_out=None, cross_cache=None):
     return x + y, nc
 
 
+def _add(a: dict, b: dict) -> dict:
+    return {k: a[k] + b[k] for k in a}
+
+
 def run_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
               emb0=None, enc_out=None, causal=True):
-    """Run prelude + scanned periods. Returns (x, new_caches, aux)."""
-    aux0 = jnp.zeros((), jnp.float32)
+    """Run prelude + scanned periods. Returns (x, new_caches, aux), aux
+    summed over the blocks (``_aux``)."""
+    aux0 = _aux(cfg)
     new_caches: dict[str, Any] = {}
     shared = params.get("shared")
     has_cache = caches is not None
@@ -398,7 +421,7 @@ def run_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
             x, nc, aux = apply_block(params["prelude"][name], cfg, kind, x,
                                      mode=mode, cache=c, pos=pos, shared=shared,
                                      emb0=emb0, causal=causal)
-            aux0 = aux0 + aux
+            aux0 = _add(aux0, aux)
             new_caches["prelude"][name] = nc
 
     def period_fn(carry, scanned):
@@ -414,7 +437,7 @@ def run_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
             x, nc, aux = apply_block(bp[name], cfg, kind, x, mode=mode, cache=c,
                                      pos=pos, shared=shared, emb0=emb0,
                                      causal=causal)
-            aux_acc = aux_acc + aux
+            aux_acc = _add(aux_acc, aux)
             if nc is not None:
                 out_caches[name] = nc
             if xp is not None:
@@ -474,6 +497,15 @@ def _embed_inputs(params, cfg, batch, mode):
 def forward_train(params, cfg: ModelConfig, batch):
     """batch: tokens [B,S] (+ prefix [B,P,d] | enc_embeds [B,F,d]).
     Returns (logits [B,S,V], aux_loss)."""
+    logits, aux, _ = forward_train_counted(params, cfg, batch)
+    return logits, aux
+
+
+def forward_train_counted(params, cfg: ModelConfig, batch):
+    """``forward_train`` with the model's counters: ``(logits, aux_loss,
+    counters)``, where a model with MoE layers counts ``expert_slot_fill``,
+    the mean over its MoE layers of the share (%) of the held experts' slot
+    rows that hold a routed choice."""
     x = _embed_inputs(params, cfg, batch, "train")
     emb0 = x
     enc_out = (encode(params, cfg, batch["enc_embeds"].astype(cfg.dtype))
@@ -484,7 +516,12 @@ def forward_train(params, cfg: ModelConfig, batch):
     if cfg.prefix_len and "prefix" in batch:
         x = x[:, batch["prefix"].shape[1]:]
     logits = softcap(unembed(params["embed"], x), cfg.final_softcap)
-    return logical_constraint(logits, ("batch", "seq", "vocab")), aux
+    counters = {}
+    if cfg.moe is not None:
+        counters["expert_slot_fill"] = 100.0 * aux["fill"] / jnp.maximum(
+            aux["moe_layers"], 1.0)
+    return (logical_constraint(logits, ("batch", "seq", "vocab")),
+            aux["loss"], counters)
 
 
 def forward_prefill(params, cfg: ModelConfig, batch, caches):

@@ -40,12 +40,16 @@ from repro.train.loss import lm_loss, shift_targets
 
 
 def make_loss_fn(cfg: transformer.ModelConfig) -> Callable:
+    """``loss_fn(params, batch) -> (loss, counters)``: the model's counters
+    (``transformer.forward_train_counted``) ride out as the aux of
+    ``value_and_grad(..., has_aux=True)`` into the step's metrics."""
     def loss_fn(params, batch):
-        logits, aux = transformer.forward_train(params, cfg, batch)
+        logits, aux, counters = transformer.forward_train_counted(
+            params, cfg, batch)
         targets, mask = shift_targets(batch["tokens"])
         if "loss_mask" in batch:
             mask = mask * batch["loss_mask"]
-        return lm_loss(logits, targets, mask) + aux
+        return lm_loss(logits, targets, mask) + aux, counters
     return loss_fn
 
 
@@ -216,10 +220,11 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
 
     def grad_fn(params, batch):
         with stage("model"), shd.activation_sharding(inner_rules, mesh):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss, counters), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch)
         with stage("exchange"):
-            loss = jax.lax.pmean(loss, manual)
-        return loss, jax.tree.map(lambda g: g[None], grads)
+            loss, counters = jax.lax.pmean((loss, counters), manual)
+        return loss, counters, jax.tree.map(lambda g: g[None], grads)
 
     # out_specs of a partial-manual region may only name ITS manual axes;
     # the model-dim sharding of each leaf stays auto here and is re-bound
@@ -228,7 +233,7 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
                                   is_leaf=lambda t: isinstance(t, P))
     grad_sharded = jax.shard_map(
         grad_fn, mesh=mesh, in_specs=(P(), batch_spec),
-        out_specs=(P(), grad_out_specs),
+        out_specs=(P(), P(), grad_out_specs),
         axis_names=set(manual), check_vma=False)
 
     sync_axes = set(manual) | ({"model"} if shard_local_sync else set())
@@ -353,7 +358,7 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
             out_specs=(sync_out_specs, P()),
             axis_names=sync_axes, check_vma=False)
 
-    def _finish(loss, grads, stats, opt_state, params):
+    def _finish(loss, counters, grads, stats, opt_state, params):
         var_scale = jnp.maximum(stats.var_ratio, 1.0) if var_adaptive_lr else 1.0
         with stage("optimizer"):
             new_params, new_opt = opt.update(grads, opt_state, params,
@@ -366,7 +371,7 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
                    "wire_bytes_intra": stats.wire_bytes_intra,
                    "wire_bytes_inter": stats.wire_bytes_inter,
                    "overflow": stats.overflow, "dense_bits": stats.dense_bits,
-                   "skipped": stats.skipped}
+                   "skipped": stats.skipped, **counters}
         return new_params, new_opt, metrics
 
     def _maybe_rescale(ef_state, opt_state):
@@ -383,25 +388,25 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
         return rescale_feedback(ef_state, lr_prev, lr_now)
 
     def train_step(params, opt_state, batch, key):
-        loss, grads_stacked = grad_sharded(params, batch)
+        loss, counters, grads_stacked = grad_sharded(params, batch)
         grads, stats = sync_sharded(grads_stacked, key)
-        return _finish(loss, grads, stats, opt_state, params)
+        return _finish(loss, counters, grads, stats, opt_state, params)
 
     def train_step_ef(params, opt_state, ef_state, batch, key):
-        loss, grads_stacked = grad_sharded(params, batch)
+        loss, counters, grads_stacked = grad_sharded(params, batch)
         ef_state = _maybe_rescale(ef_state, opt_state)
         grads, new_res, stats = sync_sharded(grads_stacked,
                                              ef_state.residual, key)
-        new_params, new_opt, metrics = _finish(loss, grads, stats,
+        new_params, new_opt, metrics = _finish(loss, counters, grads, stats,
                                                opt_state, params)
         return new_params, new_opt, FeedbackState(residual=new_res), metrics
 
     def train_step_hier_ef(params, opt_state, ef_state, batch, key):
-        loss, grads_stacked = grad_sharded(params, batch)
+        loss, counters, grads_stacked = grad_sharded(params, batch)
         ef_state = _maybe_rescale(ef_state, opt_state)
         grads, new_res, new_pod_res, stats = sync_sharded(
             grads_stacked, ef_state.residual, ef_state.pod_residual, key)
-        new_params, new_opt, metrics = _finish(loss, grads, stats,
+        new_params, new_opt, metrics = _finish(loss, counters, grads, stats,
                                                opt_state, params)
         return (new_params, new_opt,
                 FeedbackState(residual=new_res, pod_residual=new_pod_res),
@@ -409,12 +414,12 @@ def make_compressed_train_step(cfg: transformer.ModelConfig,
 
     def train_step_adaptive(params, opt_state, ef_state, ctl_state, batch,
                             key):
-        loss, grads_stacked = grad_sharded(params, batch)
+        loss, counters, grads_stacked = grad_sharded(params, batch)
         ef_state = _maybe_rescale(ef_state, opt_state)
         grads, new_res, new_ls, new_la, new_b, new_step, stats = sync_sharded(
             grads_stacked, ef_state.residual, ctl_state.last_sent,
             ctl_state.last_avg, ctl_state.bound, ctl_state.step, key)
-        new_params, new_opt, metrics = _finish(loss, grads, stats,
+        new_params, new_opt, metrics = _finish(loss, counters, grads, stats,
                                                opt_state, params)
         return (new_params, new_opt, FeedbackState(residual=new_res),
                 ControlState(last_sent=new_ls, last_avg=new_la, bound=new_b,
@@ -454,11 +459,13 @@ def make_fsdp_train_step(cfg: transformer.ModelConfig,
 
     def _grads(params, batch):
         with stage("model"), shd.activation_sharding(rules, mesh):
-            return jax.value_and_grad(loss_fn)(params, batch)
+            (loss, counters), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch)
+        return loss, counters, grads
 
     def train_step(params, opt_state, batch, key):
-        loss, grads = _grads(params, batch)
-        metrics = {"loss": loss}
+        loss, counters, grads = _grads(params, batch)
+        metrics = {"loss": loss, **counters}
         if comp is not None and comp.name != "none":
             with stage("compress"):
                 q_tree, _, stats = compress_tree(comp, key, grads,
@@ -471,13 +478,13 @@ def make_fsdp_train_step(cfg: transformer.ModelConfig,
         return new_params, new_opt, metrics
 
     def train_step_ef(params, opt_state, ef_state, batch, key):
-        loss, grads = _grads(params, batch)
+        loss, counters, grads = _grads(params, batch)
         with stage("compress"):
             q_tree, new_res, stats = compress_tree(
                 comp, key, grads, residual=ef_state.residual,
                 stacked=stacked)
         metrics = {"loss": loss, "bits": stats.bits, "density": stats.density,
-                   "var_ratio": stats.var_ratio}
+                   "var_ratio": stats.var_ratio, **counters}
         with stage("optimizer"):
             new_params, new_opt = opt.update(q_tree, opt_state, params)
         return (new_params, new_opt, FeedbackState(residual=new_res),

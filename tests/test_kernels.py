@@ -289,6 +289,48 @@ class TestEmitCodecFusion:
         vals = np.asarray(er.values, np.float32)
         assert (vals != 0).all()
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("k_cap", [256, 8192], ids=["overflow", "fits"])
+    def test_ef_residual_carries_overflow(self, dtype, k_cap):
+        """The EF residual is target - transmitted, the reference backend's
+        rule (``core.sparse._residual_from_buffers``): survivors past k_cap
+        are not sent and stay in the residual whole. Where nothing
+        overflows, the residual is the kernel's in-pass ``g - sent`` and
+        the buffers are those of the emit without EF, bit for bit."""
+        from repro.comm import compaction
+        from repro.core import sparse
+        n = 70_000                               # ~3500 survivors at rho 0.05
+        g = _grad(35, (n,), dtype)
+        u = jax.random.uniform(jax.random.key(36), (n,), jnp.float32)
+        er, _ = ops.gspar_emit(g, u, k_cap=k_cap, rho=0.05, ef=True,
+                               interpret=True)
+        assert (int(er.nnz) > k_cap) == (k_cap == 256)
+        g32 = np.asarray(g, np.float32)
+        sent = np.asarray(compaction.scatter(er.values.astype(jnp.float32),
+                                             er.idx, n))
+        res = np.asarray(er.residual, np.float32)
+        # a kept coordinate's residual g - g/p rounds at the scale of g/p
+        ulp = (np.abs(g32) + np.abs(sent)) * float(jnp.finfo(dtype).eps)
+        np.testing.assert_array_less(np.abs(res + sent - g32), ulp + 1e-30)
+        sg = sparse.SparseGrad(values=er.values, idx=er.idx, nnz=er.nnz,
+                               p_sum=er.p_sum, bits=jnp.float32(0),
+                               var_ratio=jnp.float32(0), d=n, shape=(n,),
+                               codec="f32", layout="coo")
+        ref_res = np.asarray(sparse._residual_from_buffers(g, sg), np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(res, ref_res)
+        else:
+            np.testing.assert_array_less(np.abs(res - ref_res), ulp + 1e-30)
+        if k_cap == 8192:
+            np.testing.assert_array_equal(
+                res, np.asarray((g32 - sent).astype(dtype), np.float32))
+            er0, _ = ops.gspar_emit(g, u, k_cap=k_cap, rho=0.05,
+                                    interpret=True)
+            np.testing.assert_array_equal(np.asarray(er.values),
+                                          np.asarray(er0.values))
+            np.testing.assert_array_equal(np.asarray(er.idx),
+                                          np.asarray(er0.idx))
+
     @pytest.mark.parametrize("name", ["bf16", "f32"])
     def test_ef_residual_subtracts_wire_values(self, name):
         """Float-codec EF in-pass residual: exactly g minus the scatter of

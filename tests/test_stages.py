@@ -2,6 +2,7 @@
 every scatter, gather, sort and collective of the step's HLO carries a
 ``stage.<name>`` scope in its ``op_name`` metadata, so a profiler trace's
 device time can be put under the stage that spent it."""
+import dataclasses
 import re
 
 import jax
@@ -9,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.api import CompressionConfig
-from repro.core.stages import STAGES, stage
+from repro.core.stages import MOE_STAGES, STAGES, stage
 from repro.dist import sharding as shd
 from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
@@ -31,6 +32,17 @@ def _tiny_cfg():
         d_ff=128, act="gelu", norm="rms", remat="none", dtype=jnp.bfloat16)
 
 
+def _tiny_lite_cfg():
+    """The registry's deepseek-v2-lite smoke config with 4 of 8 routed
+    experts held: MLA without q-LoRA, latent norm, YaRN, held experts."""
+    from repro.configs import registry
+    cfg = registry.get("deepseek-v2-lite").smoke
+    return dataclasses.replace(
+        cfg, dtype=jnp.bfloat16, num_periods=2,
+        moe=dataclasses.replace(cfg.moe, num_experts=8, top_k=3,
+                                experts_held=4, expert_offset=2))
+
+
 def _stages_by_opcode(text: str) -> dict:
     """``{opcode: [innermost stage or None of each instruction]}`` over
     every instruction of a compiled module's HLO text."""
@@ -46,7 +58,8 @@ def _stages_by_opcode(text: str) -> dict:
 
 
 def test_stage_names():
-    assert len(set(STAGES)) == len(STAGES) == 8
+    assert len(set(STAGES)) == len(STAGES) == 12
+    assert STAGES[8:] == MOE_STAGES
     with pytest.raises(ValueError, match="unknown stage"):
         stage("collective")
     text = jax.jit(lambda x: stage("apply")(jnp.sin)(x)).lower(
@@ -54,10 +67,15 @@ def test_stage_names():
     assert "stage.apply" in text
 
 
-@pytest.mark.parametrize("exchange,backend", [
-    ("sync", "pallas"), ("overlap", "pallas"), ("sync", "reference")])
-def test_compressed_step_names_every_stage(exchange, backend):
-    cfg = _tiny_cfg()
+@pytest.mark.parametrize("model,exchange,backend", [
+    ("dense", "sync", "pallas"), ("dense", "overlap", "pallas"),
+    ("dense", "sync", "reference"), ("lite", "sync", "pallas")])
+def test_compressed_step_names_every_stage(model, exchange, backend):
+    """The step's own eight stages in every case; a model with MoE layers
+    adds route (its router matmul), dispatch (the sort and gathers into the
+    held experts' slots), experts (their matmuls) and combine (the gather
+    back)."""
+    cfg = _tiny_cfg() if model == "dense" else _tiny_lite_cfg()
     mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig(name="gspar", rho=0.05, wire="gather",
                              wire_layout="auto", backend=backend,
@@ -79,7 +97,14 @@ def test_compressed_step_names_every_stage(exchange, backend):
         assert None not in by_op[op], op
     assert {"scatter", "gather", "all-gather"} <= set(by_op)
     found = {s for stages in by_op.values() for s in stages}
-    assert set(STAGES) <= found, set(STAGES) - found
+    want = set(STAGES) - (set(MOE_STAGES) if model == "dense" else set())
+    assert want <= found, want - found
+    if model == "lite":
+        assert {"route", "experts"} <= set(by_op["dot"])
+        assert {"dispatch", "combine"} <= set(by_op["gather"])
+        assert "dispatch" in by_op["sort"]
+    else:
+        assert not set(MOE_STAGES) & found
 
 
 def test_fsdp_step_names_its_stages():

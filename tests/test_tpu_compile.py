@@ -102,7 +102,7 @@ def test_two_pass_kernels_compile_at_mlp_row(one_chip, pkind):
         tiles, _, _ = K.select_stats_2d(g, u, 0.5, 3.0, up, lo, pkind=pkind)
         offsets = jnp.cumsum(tiles, axis=1) - tiles
         return K.compact_emit_2d(g, u, 0.5, 3.0, offsets, up, lo,
-                                 pkind=pkind, ef=True)
+                                 pkind=pkind, k_cap=4096, ef=True)
 
     hlo = _hlo(two_pass, g, u)
     _assert_kernels(hlo, f"select_stats_{pkind}", f"compact_emit_{pkind}")
