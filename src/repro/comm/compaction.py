@@ -386,7 +386,7 @@ def rice_decode(words: jax.Array, k_cap: int, d: int, r: int) -> jax.Array:
             [u[:, w0 + 1:], jnp.full((rows, 1), 0xFFFFFFFF, jnp.uint32)],
             axis=1)
         uw = (uw >> s) | (nxt << (WORD_BITS - s))
-    zpos = _rice_terminators(uw, k_cap, n_words * WORD_BITS - k_cap * r)
+    zpos = first_zero_bits(uw, k_cap, n_words * WORD_BITS - k_cap * r)
     # code i's quotient is zpos_i - zpos_{i-1} - 1 (zpos_{-1} = -1), so the
     # quotients of codes 0..i sum to zpos_i - i, and coordinate i (the
     # gaps (q << r | rem) + 1 summed, less one) is, with int32 wrap-around:
@@ -425,24 +425,29 @@ def _rice_remainders(u: jax.Array, k_cap: int, r: int) -> jax.Array:
     return rem.reshape(rows, nb * WORD_BITS)[:, :k_cap].astype(jnp.int32)
 
 
-def _rice_terminators(uw: jax.Array, k_cap: int, u_cap: int) -> jax.Array:
-    """Bit position in the unary region ``uw [rows, n_u]`` of each code's
-    terminator, the (i+1)-th zero bit -> ``[rows, k_cap]`` int32, or
-    ``u_cap`` where the row holds fewer zeros.
+def first_zero_bits(uw: jax.Array, k_cap: int, u_cap: int) -> jax.Array:
+    """Bit position (LSB-first, word by word) of the (i+1)-th zero bit of
+    each row of ``uw [rows, n_u]`` uint32 -> ``[rows, k_cap]`` int32,
+    ascending, or ``u_cap`` where the row holds fewer zeros. Two callers:
+    the Rice decoder, whose zeros in the unary region are the codes'
+    terminators, and the emit ops' compaction
+    (``repro.kernels.sparsify.ops``), whose zeros in a complemented keep
+    mask are the kept coordinates.
 
-    A rank histogram and prefix scans, not a search. Word w, with ex[w]
-    zeros before it, holds terminators ex[w] + 1 .. ex[w] + zeros[w].
-    Every word is added to slot min(ex[w], k_cap) of a per-row table of
-    k_cap + 1 slots (the last drops the words past code k_cap); ex never
-    falls along a row, so the flat slot indices are sorted. For slot s,
-    the last word W with ex[W] <= s holds terminator s + 1 (if the row has
-    that many zeros), and prefix scans over the slots give W (the words
-    counted, less one), its bits (each word added as its difference from
-    the word before it) and ex[W] (the last slot any word landed in).
+    A rank histogram and prefix scans, not a search: its cost grows with
+    n_u + k_cap. Word w, with ex[w] zeros before it, holds zeros ex[w] + 1
+    .. ex[w] + zeros[w]. Every word is added to slot min(ex[w], k_cap) of a
+    per-row table of k_cap + 1 slots (the last drops the words past zero
+    k_cap); ex never falls along a row, so the flat slot indices are
+    sorted. For slot s, the last word W with ex[W] <= s holds zero s + 1
+    (if the row has that many), and prefix scans over the slots give W
+    (the words counted, less one), its bits (each word added as its
+    difference from the word before it) and ex[W] (the last slot any
+    word landed in).
     """
     rows = uw.shape[0]
     zeros = WORD_BITS - jax.lax.population_count(uw).astype(jnp.int32)
-    ex = jnp.cumsum(zeros, axis=1) - zeros
+    ex = _cumsum(zeros) - zeros
     row = jnp.arange(rows, dtype=jnp.int32)[:, None]
     slot = (row * (k_cap + 1) + jnp.minimum(ex, k_cap)).reshape(-1)
 
@@ -455,13 +460,39 @@ def _rice_terminators(uw: jax.Array, k_cap: int, u_cap: int) -> jax.Array:
     prev = jnp.concatenate([jnp.zeros((rows, 1), jnp.uint32), uw[:, :-1]],
                            axis=1)
     hist = ranked(jnp.ones_like(zeros))
-    wi = jnp.cumsum(hist, axis=1) - 1
-    word = jnp.cumsum(ranked(uw - prev), axis=1)
+    wi = _cumsum(hist) - 1
+    word = _cumsum(ranked(uw - prev))
     s = jnp.arange(k_cap, dtype=jnp.int32)
-    ex_w = jax.lax.cummax(jnp.where(hist > 0, s, -1), axis=1)
+    ex_w = _scan(jnp.where(hist > 0, s, -1), jax.lax.cummax, jnp.maximum)
     bit = _nth_set_bit(~word, s + 1 - ex_w)
     return jnp.where(s < jnp.sum(zeros, axis=1, keepdims=True),
                      wi * WORD_BITS + bit, u_cap)
+
+
+SCAN_BLOCK = 1024
+
+
+def _scan(x: jax.Array, scan, combine) -> jax.Array:
+    """Inclusive ``scan`` (``jnp.cumsum`` or ``jax.lax.cummax``, whose
+    binary op is ``combine``) along the rows of ``x [rows, n]``, in blocks
+    of SCAN_BLOCK: the TPU compiler takes tens of seconds over one scan of
+    a few million elements, and about one over blocks of them and a scan
+    of the block totals."""
+    rows, n = x.shape
+    if n <= SCAN_BLOCK:
+        return scan(x, axis=1)
+    m = -(-n // SCAN_BLOCK) * SCAN_BLOCK
+    y = scan(jnp.pad(x, ((0, 0), (0, m - n))).reshape(rows, -1, SCAN_BLOCK),
+             axis=2)
+    through = _scan(y[:, :, SCAN_BLOCK - 1], scan, combine)  # to block ends
+    # (index with a slice, then a new axis: both in one index is a gather)
+    y = jnp.concatenate(
+        [y[:, :1], combine(y[:, 1:], through[:, :-1][:, :, None])], axis=1)
+    return y.reshape(rows, m)[:, :n]
+
+
+def _cumsum(x: jax.Array) -> jax.Array:
+    return _scan(x, jnp.cumsum, jnp.add)
 
 
 def _nth_set_bit(x: jax.Array, n: jax.Array) -> jax.Array:

@@ -18,11 +18,12 @@ Kernel families:
                the variance denominator in one traversal;
                ``compact_emit_2d`` (pass 2) re-derives the kept mask and
                gives every survivor its global compact rank (the tile's
-               offset plus an in-tile prefix count on the MXU). One XLA
-               scatter of (rank, value) then builds the compact
-               ``(values, idx)`` wire buffers: Mosaic has no lowering for
-               cumsum, vector scatter or 1-D gather, so nothing of size
-               k_cap lives in the kernels.
+               offset plus an in-tile prefix count on the MXU). A rank
+               select over the packed keep mask and one k_cap gather of
+               the values then build the compact ``(values, idx)`` wire
+               buffers in XLA (``ops._two_pass``): Mosaic has no lowering
+               for cumsum, vector scatter or 1-D gather, so nothing of
+               size k_cap lives in the kernels.
 
 Block layout: inputs are reshaped to [R, C] with C a multiple of 128 and
 R a multiple of 8; tiles of (BLOCK_R, BLOCK_C) f32 live in VMEM
@@ -418,8 +419,8 @@ def select_stats_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
     return tiles, psum[0, 0], den[0, 0]
 
 
-# slot of a coordinate that is not kept: past any capacity, so the scatter
-# that builds the compact buffers drops it
+# slot of a coordinate that is not kept: past any capacity, and the mark
+# the compact select's keep mask reads (slot != DROP_SLOT)
 DROP_SLOT = 2**31 - 1
 
 
@@ -455,8 +456,10 @@ def compact_emit_2d(g: jax.Array, u: jax.Array, s1: jax.Array, s2: jax.Array,
     the tiles are independent. Emits ``(slot [r, c] int32, v [r, c] f32,
     residual)``: ``slot`` is the compact rank of each survivor (DROP_SLOT
     elsewhere) and ``v`` its amplified value; the compact ``(values, idx)``
-    buffers are one XLA scatter of these, which drops ranks past
-    ``k_cap``. ``ef=True`` additionally emits ``residual [r, c]`` = g minus
+    buffers are the first ``k_cap`` survivors in coordinate order, which
+    a rank select over ``slot != DROP_SLOT`` finds and a gather of ``v``
+    fills (``ops._two_pass``), so ranks past ``k_cap`` are dropped.
+    ``ef=True`` additionally emits ``residual [r, c]`` = g minus
     what is sent: the values of the survivors ranked under ``k_cap``,
     rounded to ``wire_dtype`` (None when not requested)."""
     r, c = g.shape

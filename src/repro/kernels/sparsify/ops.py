@@ -11,7 +11,8 @@ The ``*_emit`` family is the two-pass compaction pipeline. Pass 1
 counts and the p/variance accounting in one traversal; their exclusive
 prefix sums are the tile offsets of pass 2 (``compact_emit_2d``), which
 re-derives the kept mask, ranks every survivor and emits the optional EF
-residual in the same pass. One XLA scatter of (rank, value) builds the
+residual in the same pass. A rank select over the packed keep mask
+finds the kept coordinates and one gather their values, which build the
 compact ``(values, idx)`` buffers, and ``codec.encode`` runs on that
 compact buffer exactly as on the reference backend. One emit wrapper per
 selector:
@@ -33,7 +34,9 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.comm import compaction
 from repro.core import codecs as codecs_lib
 from repro.core import sparsify as sparsify_lib
 from repro.core.stages import stage
@@ -162,14 +165,46 @@ class EmitResult(NamedTuple):
 _F32 = codecs_lib.FloatCodec()
 
 
+def _keep_words(slot: jax.Array) -> jax.Array:
+    """The keep mask of a slot map ``[r, c]`` packed LSB-first into one row
+    of uint32 words ``[1, r * c / 32]``: word w holds coordinates 32w ..
+    32w + 31.
+
+    One matmul packs it: the 0/1 mask in bfloat16 times a ``[c, 2c/32]``
+    matrix of powers of two sums bits 0-15 and bits 16-31 of every word
+    as floats under 2**16, which the float32 accumulator holds exactly.
+    Strided slices (one per bit) made the compiler materialise the mask
+    and each slice, eight bytes a coordinate, where the matmul's output
+    takes one."""
+    r, c = slot.shape
+    n = c // compaction.WORD_BITS
+    half = compaction.WORD_BITS // 2
+    col = np.arange(c)
+    bit = col % compaction.WORD_BITS
+    weights = np.zeros((c, 2 * n), np.float32)
+    weights[col, col // compaction.WORD_BITS + n * (bit >= half)] = \
+        2.0 ** (bit % half)
+    keep = (slot != K.DROP_SLOT).astype(jnp.bfloat16)
+    halves = jnp.dot(keep, jnp.asarray(weights, jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.uint32)
+    return (halves[:, :n] | (halves[:, n:] << half)).reshape(1, -1)
+
+
 def _two_pass(flat: jax.Array, u: jax.Array | None, s1, s2, *, pkind: str,
               codec, k_cap: int, ef: bool, u_cod: jax.Array | None,
               interpret: bool) -> EmitResult:
     """Shared two-pass driver: pass 1 per-tile counts, exclusive tile
-    offsets, pass 2 ranks, then the compact scatter and the codec encode.
+    offsets, pass 2 ranks, then the compact select and the codec encode.
     ``u`` is the selector's pregenerated uniforms (ignored for
     deterministic selectors), ``u_cod`` the codec's (length k_cap, one per
-    compact rank)."""
+    compact rank).
+
+    The compact select never touches a coordinate one by one: the keep
+    mask (``slot != DROP_SLOT``) packs into words, the rank histogram of
+    ``compaction.first_zero_bits`` finds the first k_cap kept coordinates
+    in ascending order from the complemented words (ranks past k_cap are
+    never produced), and one k_cap gather reads their values. Its cost
+    grows with d/32 + k_cap, not with d."""
     g2d, n, _, _ = _pad_2d(flat)
     if u is not None:
         u2d, _, _, _ = _pad_2d(u.reshape(-1).astype(jnp.float32))
@@ -185,13 +220,15 @@ def _two_pass(flat: jax.Array, u: jax.Array | None, s1, s2, *, pkind: str,
                                          pkind=pkind, k_cap=k_cap,
                                          wire_dtype=wire_dtype, ef=ef,
                                          interpret=interpret)
-        # scatter from the kernels' 2-D layout: flattening slot first costs
-        # the TPU compiler a minute per vmapped group and buys nothing
-        vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
-        coord = (jax.lax.broadcasted_iota(jnp.int32, slot.shape, 0)
-                 * slot.shape[1]
-                 + jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1))
-        idx = jnp.zeros((k_cap,), jnp.int32).at[slot].set(coord, mode="drop")
+        coord = compaction.first_zero_bits(~_keep_words(slot), k_cap,
+                                           slot.size)[0]
+        live = coord < slot.size
+        idx = jnp.where(live, coord, 0)
+        # coord ascends, its tail (slot.size) clamped to the last coordinate;
+        # gathered from the 2-D v: flattening it first copies the leaf
+        at = jnp.minimum(coord, slot.size - 1)
+        vals = jnp.where(live, v.at[at // v.shape[1], at % v.shape[1]].get(
+            indices_are_sorted=True, mode="promise_in_bounds"), 0.0)
         scale = codec.scale(vals)
         values = codec.encode(vals, scale, u_cod).astype(wire_dtype)
     if ef:

@@ -351,6 +351,136 @@ class TestEmitCodecFusion:
             np.asarray(g, np.float32) - np.asarray(sent))
 
 
+class TestCompactSelect:
+    """The compact buffers come from a rank select over the packed keep
+    mask. They must equal, bit for bit, what the scatter form built: the
+    pass-2 kernel's ``(slot, v)`` scattered by
+    ``.at[slot].set(..., mode="drop")`` into k_cap slots. Values ride the
+    qsgd8 wire without EF and the bf16 wire with it."""
+
+    N = 20_480
+    CASES = {                      # (n, rows, k_cap, fill)
+        "overflow": (N, 0, 256, "grad"),
+        "fits": (N, 0, 8192, "grad"),
+        "zero": (N, 0, 8192, "zero"),
+        "all": (N, 0, N, "all"),
+        "ragged": (20_001, 0, 8192, "grad"),
+        "vmap3": (N, 3, 256, "grad"),
+    }
+
+    @staticmethod
+    def _scatter_oracle(flat, u, s1, s2, *, pkind, codec, k_cap, ef, u_cod):
+        g2d, n, _, _ = ops._pad_2d(flat)
+        u2d = g2d if u is None else ops._pad_2d(u)[0]
+        up, lo = K.prefix_operands()
+        tiles, _, _ = K.select_stats_2d(g2d, u2d, s1, s2, up, lo,
+                                        pkind=pkind, interpret=True)
+        offsets = jnp.cumsum(tiles, axis=1) - tiles
+        wire_dtype = codec.wire_dtype(flat.dtype)
+        slot, v, res = K.compact_emit_2d(g2d, u2d, s1, s2, offsets, up, lo,
+                                         pkind=pkind, k_cap=k_cap,
+                                         wire_dtype=wire_dtype, ef=ef,
+                                         interpret=True)
+        vals = jnp.zeros((k_cap,), jnp.float32).at[slot].set(v, mode="drop")
+        coord = (jax.lax.broadcasted_iota(jnp.int32, slot.shape, 0)
+                 * slot.shape[1]
+                 + jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1))
+        idx = jnp.zeros((k_cap,), jnp.int32).at[slot].set(coord, mode="drop")
+        scale = codec.scale(vals)
+        values = codec.encode(vals, scale, u_cod).astype(wire_dtype)
+        return (values, idx, jnp.sum(tiles[0]), scale,
+                res.reshape(-1)[:n] if ef else None)
+
+    @staticmethod
+    def _selector(pkind, g, fill):
+        """(s1, s2) of the selector for a row, as the emit wrappers derive
+        them; "all" keeps every nonzero coordinate."""
+        a = jnp.abs(g)
+        n = g.shape[0]
+        if pkind == "lam":
+            lam = jnp.where(a.sum() > 0, 0.05 * n / a.sum(), 0.0)
+            return lam, jnp.float32(0)
+        if pkind == "rho":
+            return jnp.float32(1.0 if fill == "all" else 0.05), \
+                jnp.float32(0)
+        if pkind == "bern":
+            return jnp.float32(0), a.max()
+        k = n if fill == "all" else n // 20
+        topv = jax.lax.top_k(a, k)[0]
+        t = topv[-1]
+        return t, jnp.float32(k) - jnp.count_nonzero(topv > t)
+
+    @pytest.mark.parametrize("ef", [False, True], ids=["plain", "ef"])
+    @pytest.mark.parametrize("pkind", ["lam", "rho", "bern", "topk"])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_select_matches_scatter_oracle(self, case, pkind, ef):
+        from repro.core import codecs as codecs_lib
+        n, rows, k_cap, fill = self.CASES[case]
+        codec = codecs_lib.get("bf16" if ef else "qsgd8")
+        shape = (max(rows, 1), n)
+        g = (jnp.zeros(shape, jnp.float32) if fill == "zero"
+             else _grad(60, shape, jnp.float32))
+        u = (jnp.zeros(shape, jnp.float32) if fill == "all" else
+             jax.random.uniform(jax.random.key(61), shape, jnp.float32))
+        u_cod = jax.random.uniform(jax.random.key(62), (shape[0], k_cap),
+                                   jnp.float32)
+        kw = dict(pkind=pkind, codec=codec, k_cap=k_cap, ef=ef)
+
+        def both(g, u, u_cod):
+            s1, s2 = self._selector(pkind, g, fill)
+            uu = None if pkind == "topk" else u
+            er = ops._two_pass(g, uu, s1, s2, u_cod=u_cod, interpret=True,
+                               **kw)
+            got = (er.values, er.idx, er.nnz, er.scale, er.residual)
+            return got, self._scatter_oracle(g, uu, s1, s2, u_cod=u_cod,
+                                             **kw)
+
+        run = jax.jit(jax.vmap(both) if rows else
+                      lambda *a: both(*(x[0] for x in a)))
+        got, want = run(g, u, u_cod)
+        nnz = np.asarray(want[2]).reshape(-1)
+        if fill == "grad":
+            assert ((nnz > k_cap) == (k_cap == 256)).all()
+        elif fill == "all":
+            assert (nnz == n).all()
+        else:
+            assert (nnz == 0).all()
+        for name, a, b in zip(["values", "idx", "nnz", "scale", "residual"],
+                              got, want):
+            if b is None:
+                assert a is None
+                continue
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+
+    @pytest.mark.parametrize("log_d", [16, 20])
+    def test_compact_cost_does_not_grow_with_the_leaf(self, log_d):
+        """No sort, and no scatter to more places than the leaf has
+        32-bit words of keep mask: the compaction's work grows with
+        d/32 + k_cap, not with d. (Padding the leaf to the kernels' layout
+        is one scatter of a whole window, at one place.)"""
+        d = 2 ** log_d
+        k_cap = d // 16
+
+        def eqns(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else (v,):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from eqns(sub)
+
+        spec = jax.ShapeDtypeStruct((d,), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda g, u: ops.gspar_emit(
+            g, u, k_cap=k_cap, rho=0.05, ef=True))(spec, spec)
+        found = list(eqns(jaxpr.jaxpr))
+        assert not [e for e in found if "sort" in e.primitive.name]
+        places = [int(np.prod(e.invars[1].aval.shape[:-1])) for e in found
+                  if e.primitive.name.startswith("scatter")]
+        assert places and max(places) == d // 32
+
+
 class TestEmitRicePacking:
     """The emit's coordinate-ordered valid prefix lets the RICE layout pack
     without a sort (``rice_encode(nnz=...)``, what ``wire_layout.pack``

@@ -12,6 +12,7 @@ only one process may load the TPU library, and every pytest-xdist worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,7 +112,8 @@ def test_two_pass_kernels_compile_at_mlp_row(one_chip, pkind):
 
 def test_gspar_f32_ef_emit_compiles_at_mlp_row(one_chip):
     """The whole fused selector as the backend runs it: stats, greedy
-    lambda, both passes, the compact scatter, the in-pass EF residual."""
+    lambda, both passes, the compact select, the in-pass EF residual.
+    The select sorts nothing: no ``sort`` instruction in the program."""
     cfg = CompressionConfig(name="gspar", rho=RHO, wire="gather",
                             error_feedback=True, backend="pallas")
     backend = PallasBackend(interpret=False)
@@ -125,6 +127,7 @@ def test_gspar_f32_ef_emit_compiles_at_mlp_row(one_chip):
     key = jax.random.key(0)
     hlo = _hlo(emit, _sds(key.shape, key.dtype, one_chip), g)
     _assert_kernels(hlo, "select_stats_lam", "compact_emit_lam")
+    assert not re.search(r"\ssort\(", hlo)
 
 
 def test_qsgd8_rice_emit_compiles_at_mlp_row(one_chip):
